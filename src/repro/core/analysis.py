@@ -829,8 +829,8 @@ def corrupt_schedule(schedule, rng) -> Optional[tuple[object, dict]]:
 
 def corpus_programs(include_traced: bool = True) -> dict:
     """name -> zero-arg constructor for every corpus program the CLI/CI
-    lints: the paper benchmarks, the fusion chains, both figures, and (when
-    jax is importable) the bundled traced kernels."""
+    lints: the paper benchmarks, the fusion chains, both figures, and the
+    bundled traced kernels."""
     from . import programs as P
 
     reg = dict(P.BENCHMARKS)
@@ -838,13 +838,10 @@ def corpus_programs(include_traced: bool = True) -> dict:
     reg["fig1_conv_chain"] = P.fig1_conv_chain
     reg["fig3_conv1d"] = P.fig3_conv1d
     if include_traced:
-        try:
-            from . import frontend as F
-            reg["traced_wkv6"] = lambda: F.wkv6_program().program
-            reg["traced_conv_block"] = lambda: F.conv_block_program().program
-            reg["traced_attention"] = lambda: F.attention_program().program
-        except Exception:   # pragma: no cover - jax-less environments
-            pass
+        from . import frontend as F
+        reg["traced_wkv6"] = lambda: F.wkv6_program().program
+        reg["traced_conv_block"] = lambda: F.conv_block_program().program
+        reg["traced_attention"] = lambda: F.attention_program().program
     return reg
 
 

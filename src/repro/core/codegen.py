@@ -23,7 +23,7 @@ Two lowering strategies (DESIGN.md §10):
     Pallas' grid pipeline machinery ping-pongs the output block buffers, so
     tile ``t+1``'s refill overlaps tile ``t``'s compute.
   - ``buffering="single"`` emits the same stage body inside a
-    ``lax.fori_loop`` over tiles with explicit ``pl.store`` of each tile —
+    ``lax.fori_loop`` over tiles with an explicit ref store of each tile —
     one window, serialized refill/compute/store.  It exists as the
     measurable baseline the double-buffered variant must beat.
 
@@ -37,8 +37,8 @@ Two lowering strategies (DESIGN.md §10):
   Canonical accumulations — the innermost iv absent from the store index,
   every load of the stored array at the store address (``two_mm``-style
   matmuls) — vectorize the outer ivs and fold the innermost one with a
-  ``lax.fori_loop`` left fold, which matches the sequential float rounding
-  bit for bit.
+  left fold unrolled at trace time, which matches the sequential float
+  rounding bit for bit.
 
 Programs outside both contracts (multi-chain tasks, imperfect nests, loose
 top-level ops, non-canonical reductions — the shape vocabulary is
@@ -62,7 +62,10 @@ from .errors import NestContractViolation, UnlowerableProgram
 from .ir import (AffExpr, ArithOp, ConstOp, LoadOp, Loop, Program, StoreOp,
                  nest_shape)
 
-DEFAULT_BLOCK_ROWS = 8
+# Rows of one f32 (8, 128) vreg tile on a TPU: the chip's compiler accepts a
+# gridded block only when its second-to-last dim is a multiple of this or
+# spans the whole array.  Also the row block when none is given.
+SUBLANE_ROWS = 8
 
 _ARITH_FMT = {
     "add": "({} + {})",
@@ -467,9 +470,45 @@ def _affine_t(coef: int, const: int) -> str:
     return f"{coef} * t + {const}"
 
 
-def _sl(lo: int, hi: int, step: int) -> str:
-    s = f"{lo}:{hi}"
-    return s + (f":{step}" if step > 1 else "")
+def _sel(const: int, coef: int, n: int):
+    """Index item for the ``n`` elements ``coef * i + const``: a unit-stride
+    slice, or a ``(start, n, step)`` triple that :func:`_index` decimates."""
+    return f"{const}:{const + n}" if coef == 1 else (const, n, coef)
+
+
+def _index(v: str, items: list) -> str:
+    """Source for ``v[items]``.  A str item indexes as written (one without
+    a colon drops its axis); a ``(start, n, step)`` item is sliced whole and
+    decimated by the emitted ``_take``."""
+    expr = f"{v}[" + ", ".join(":" if isinstance(it, tuple) else it
+                               for it in items) + "]"
+    axis = 0
+    for it in items:
+        if isinstance(it, tuple):
+            expr = f"_take({expr}, {axis}, {it[0]}, {it[1]}, {it[2]})"
+        if isinstance(it, tuple) or ":" in it:
+            axis += 1
+    return expr
+
+
+# A strided slice of a value has no lowering on the chip (Mosaic lowers
+# neither strided vector slices nor, for a last dim other than 128, strided
+# loads), so the generated module takes a run of ``n * step`` rows, splits
+# it into (n, step) with a reshape and keeps one phase; columns go through a
+# transpose.  Both are exact data movement.
+_TAKE_HELPER = '''
+
+def _take(x, axis, start, n, step):
+    """x[start : start + step * (n - 1) + 1 : step] along axis 0 or 1."""
+    if axis == 1:
+        return _take(x.T, 0, start, n, step).T
+    phase = min(step - 1, start)
+    over = start - phase + n * step - x.shape[0]
+    if over > 0:
+        x = jnp.concatenate([x, jnp.zeros((over,) + x.shape[1:], x.dtype)])
+    lo = start - phase
+    return x[lo:lo + n * step].reshape((n, step) + x.shape[1:])[:, phase]
+'''
 
 
 def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
@@ -481,8 +520,30 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
     produced = {s.out: s for s in plan.stages}
     refs = [f"r_{_ident(a)}" for a in plan.inputs]
 
+    # One window load per (input, row rate), starting on a sublane-tile
+    # boundary; every tap is a static slice of it.  The chip's compiler
+    # refuses a dynamic row offset it cannot prove to be tile-aligned (a
+    # one-step grid has t = 0, so its offsets are static).
+    spans: dict[tuple, list[int]] = {}  # (array, rate) -> [lo, hi) rows
+    for s in plan.stages:
+        for _, acc in s.nest.loads:
+            if acc.array in produced:
+                continue
+            rc, rk = acc.dims[0][1], acc.dims[0][2]
+            lo = rc * s.win_b + rk
+            hi = lo + rc * (s.win_sz - 1) + 1
+            w = spans.setdefault((acc.array, rc * s.win_a), [lo, hi])
+            w[0], w[1] = min(w[0], lo), max(w[1], hi)
     body: list[str] = []
-    loadcache: dict[tuple, str] = {}
+    windows: dict[tuple, tuple[str, int]] = {}  # (array, rate) -> (var, lo)
+    for (arr, a), (lo, hi) in spans.items():
+        lo -= lo % SUBLANE_ROWS
+        start = _affine_t(a if T > 1 else 0, lo)
+        if T > 1 and a % SUBLANE_ROWS == 0:
+            start = f"pl.multiple_of({start}, {SUBLANE_ROWS})"
+        ld = f"ld_{_ident(arr)}{len(windows)}"
+        body.append(f"{ld} = r_{_ident(arr)}[pl.ds({start}, {hi - lo}), :]")
+        windows[(arr, a)] = ld, lo
     final = None
     for s in plan.stages:
         tag = s.nest.loop.ivname
@@ -496,32 +557,18 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
             elif isinstance(op, LoadOp):
                 acc = next(a for o, a in s.nest.loads if o is op)
                 (_, rc, rk), (civ, cc, ck) = acc.dims
-                rowsel = f"::{rc}" if rc > 1 else ":"
                 if acc.array in produced:
                     prod = produced[acc.array]
                     rel = rc * s.win_b + rk - prod.r0 - prod.win_b
-                    rsel = _sl(rel, rel + rc * (s.win_sz - 1) + 1, rc)
-                    if civ is None:
-                        csel = _sl(ck - prod.c0, ck - prod.c0 + 1, 1)
-                    else:
-                        csel = _sl(ck - prod.c0,
-                                   ck - prod.c0 + cc * (csz - 1) + 1, cc)
-                    expr = f"w_{_ident(acc.array)}[{rsel}, {csel}]"
+                    c0 = ck - prod.c0
+                    src = f"w_{_ident(acc.array)}"
                 else:
-                    start = _affine_t(rc * s.win_a, rc * s.win_b + rk)
-                    span = rc * (s.win_sz - 1) + 1
-                    key = (acc.array, start, span)
-                    if key not in loadcache:
-                        ld = f"ld_{_ident(acc.array)}{len(loadcache)}"
-                        body.append(
-                            f"{ld} = pl.load(r_{_ident(acc.array)}, "
-                            f"(pl.dslice({start}, {span}), slice(None)))")
-                        loadcache[key] = ld
-                    if civ is None:
-                        csel = _sl(ck, ck + 1, 1)
-                    else:
-                        csel = _sl(ck, ck + cc * (csz - 1) + 1, cc)
-                    expr = f"{loadcache[key]}[{rowsel}, {csel}]"
+                    src, lo = windows[(acc.array, rc * s.win_a)]
+                    rel = rc * s.win_b + rk - lo
+                    c0 = ck
+                csel = (f"{c0}:{c0 + 1}" if civ is None
+                        else _sel(c0, cc, csz))
+                expr = _index(src, [_sel(rel, rc, s.win_sz), csel])
                 names[op.result] = _vname(op.result)
                 body.append(f"{names[op.result]} = {expr}")
             elif isinstance(op, ArithOp):
@@ -545,6 +592,10 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
         "from jax.experimental import pallas as pl",
         "",
         f"DTYPE = jnp.dtype('{dtype}')",
+    ]
+    if any("_take(" in b for b in body):
+        lines.append(_TAKE_HELPER.rstrip("\n"))
+    lines += [
         "",
         "",
         "def _kernel(" + ", ".join(refs + ["o_ref"]) + "):",
@@ -556,14 +607,11 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
     else:
         lines.append("    def _tile(t, carry):")
         lines += ["        " + b for b in body]
-        lines.append(f"        pl.store(o_ref, (pl.dslice({B} * t, {B}), "
-                     f"slice(None)), {store_val})")
+        lines.append(f"        o_ref[pl.ds({B} * t, {B}), :] = {store_val}")
         lines.append("        return carry")
         lines.append(f"    jax.lax.fori_loop(0, {T}, _tile, 0)")
     lines += ["", "",
-              "def run(arrays, interpret=None):",
-              "    if interpret is None:",
-              "        interpret = jax.default_backend() != 'tpu'"]
+              "def run(arrays, interpret=False):"]
     args = []
     specs = []
     for a in plan.inputs:
@@ -598,15 +646,25 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
 
 # Strided stores can't use `.at[::step].set` inside a Pallas kernel (the
 # scatter lowering captures index constants, which pallas_call rejects), so
-# the generated module spreads the value with repeat/pad and selects the
-# strided positions with an iota mask — all Pallas-legal primitives.
+# the generated module spreads the value and selects the strided positions
+# with an iota mask.  The spread is a broadcast and a row-merging reshape
+# (columns via a transpose): the chip lowers no lane-splitting reshape,
+# which is what jnp.repeat along columns becomes.
 _STRIDED_SET_HELPER = '''
+
+def _spread(x, axis, step):
+    """jnp.repeat(x, step, axis) for axis 0 or 1."""
+    if axis == 1:
+        return _spread(x.T, 0, step).T
+    wide = jnp.broadcast_to(x[:, None], (x.shape[0], step) + x.shape[1:])
+    return wide.reshape((x.shape[0] * step,) + x.shape[1:])
+
 
 def _strided_set(dst, val, starts, steps):
     sp = val
     for ax, st in enumerate(steps):
         if st > 1:
-            sp = jnp.repeat(sp, st, axis=ax)
+            sp = _spread(sp, ax, st)
     sp = sp[tuple(slice(0, dst.shape[a] - starts[a]) for a in range(sp.ndim))]
     sp = jnp.pad(sp, tuple(
         (starts[a], dst.shape[a] - starts[a] - sp.shape[a])
@@ -658,22 +716,24 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
         trips = nest.trips
         if nest.red_iv is not None:
             # canonical accumulation: vectorize the outer ivs, fold the
-            # innermost one with lax.fori_loop — a left fold in program
-            # order, so the float rounding matches sequential_exec bit for
-            # bit (the _exact golden tests rely on this)
+            # innermost one — a left fold in program order, so the float
+            # rounding matches sequential_exec bit for bit (the _exact
+            # golden tests rely on this).  The fold is a Python loop, so
+            # every step indexes its operands statically: the chip lowers
+            # no dynamic_slice of a value, which a lax.fori_loop fold
+            # indexing by its counter becomes.
             _, sacc = nest.stores[0]
             init(sacc.array)
             red_outs = [ivn for ivn, _, _ in sacc.dims]
             nk = trips[-1]
             acc_shape = tuple(trips[ivpos[ivn]] for ivn, _, _ in sacc.dims)
-            sels = [_sl(const, const + coef * (trips[ivpos[ivn]] - 1) + 1,
-                        coef)
+            sels = [_sel(const, coef, trips[ivpos[ivn]])
                     for ivn, coef, const in sacc.dims]
             dst = f"v_{_ident(sacc.array)}"
             body.append(f"# nest {nest.loop.ivname}: reduction over "
                         f"'{nest.red_iv}' ({nk} steps), domain {acc_shape}")
             acc0 = f"a_red{red_count}"
-            body.append(f"{acc0} = {dst}[" + ", ".join(sels) + "]")
+            body.append(f"{acc0} = {_index(dst, sels)}")
             inner: list[str] = []
             names = {}
             final = None
@@ -695,11 +755,10 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
                             lsels.append(f"{ix} + {const}" if const else ix)
                         else:
                             n = trips[ivpos[ivn]]
-                            lsels.append(
-                                _sl(const, const + coef * (n - 1) + 1, coef))
+                            lsels.append(_sel(const, coef, n))
                             val_axes.append(ivn)
-                    expr = (f"v_{_ident(acc_.array)}[" + ", ".join(lsels)
-                            + "]" + _align_suffix(val_axes, red_outs))
+                    expr = (_index(f"v_{_ident(acc_.array)}", lsels)
+                            + _align_suffix(val_axes, red_outs))
                     names[op.result] = _vname(op.result)
                     inner.append(f"{names[op.result]} = {expr}")
                 elif isinstance(op, ArithOp):
@@ -713,8 +772,8 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
             body += ["    " + b for b in inner]
             body.append(f"    return jnp.broadcast_to({final}, "
                         f"{acc_shape!r}).astype(DTYPE)")
-            body.append(f"{acc0} = jax.lax.fori_loop(0, {nk}, "
-                        f"_red{red_count}, {acc0})")
+            body.append(f"for _k in range({nk}):")
+            body.append(f"    {acc0} = _red{red_count}(_k, {acc0})")
             starts = [const for _, _, const in sacc.dims]
             steps = [coef for _, coef, _ in sacc.dims]
             shape = p.arrays[sacc.array].shape
@@ -745,12 +804,11 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
                 sels, axis_ivs = [], []
                 for ivn, coef, const in acc.dims:
                     if ivn is None:
-                        sels.append(_sl(const, const + 1, 1))
+                        sels.append(f"{const}:{const + 1}")
                         axis_ivs.append(None)
                     else:
                         n = trips[ivpos[ivn]]
-                        sels.append(_sl(const, const + coef * (n - 1) + 1,
-                                        coef))
+                        sels.append(_sel(const, coef, n))
                         axis_ivs.append(ivn)
                 v = f"v_{_ident(acc.array)}"
                 if len(acc.dims) == 1:
@@ -758,7 +816,7 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
                     if ivn is None:
                         expr = f"{v}[{const}]"
                     else:
-                        expr = f"{v}[{sels[0]}]"
+                        expr = _index(v, sels)
                         if len(nest.ivs) == 2 and ivpos[ivn] == 0:
                             expr += "[:, None]"
                 elif all(x is None for x in axis_ivs):
@@ -767,11 +825,11 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
                     # depth-1 nest reading a 2-D array: collapse the
                     # constant axis so the value is 1-D over the nest iv
                     if axis_ivs[0] is None:
-                        expr = f"{v}[{acc.dims[0][2]}, {sels[1]}]"
+                        expr = _index(v, [str(acc.dims[0][2]), sels[1]])
                     else:
-                        expr = f"{v}[{sels[0]}, {acc.dims[1][2]}]"
+                        expr = _index(v, [sels[0], str(acc.dims[1][2])])
                 else:
-                    expr = f"{v}[{sels[0]}, {sels[1]}]"
+                    expr = _index(v, sels)
                     # align value axes with the (outer, inner) target:
                     # transpose when an axis varies over the wrong iv
                     if any(ivn is not None and ivpos[ivn] != k
@@ -789,7 +847,7 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
                 sels, starts, steps, exts = [], [], [], []
                 for ivn, coef, const in acc.dims:
                     n = trips[ivpos[ivn]]
-                    sels.append(_sl(const, const + coef * (n - 1) + 1, coef))
+                    sels.append(_sel(const, coef, n))
                     starts.append(const)
                     steps.append(coef)
                     exts.append(n)
@@ -830,6 +888,8 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
         "",
         f"DTYPE = jnp.dtype('{dtype}')",
     ]
+    if any("_take(" in b for b in body):
+        lines.append(_TAKE_HELPER.rstrip("\n"))
     if any("_strided_set(" in b for b in body):
         lines.append(_STRIDED_SET_HELPER.rstrip("\n"))
     lines += [
@@ -839,9 +899,7 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
     ]
     lines += ["    " + b for b in body]
     lines += ["", "",
-              "def run(arrays, interpret=None):",
-              "    if interpret is None:",
-              "        interpret = jax.default_backend() != 'tpu'"]
+              "def run(arrays, interpret=False):"]
     for a in order:
         lines.append(f"    x_{_ident(a)} = jnp.asarray(arrays['{a}'], DTYPE)")
     shapes = ", ".join(
@@ -867,7 +925,7 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
 class PallasKernel:
     """An executable lowering of a Program.
 
-    ``fn(arrays, interpret=None) -> dict`` maps input arrays (by name, the
+    ``fn(arrays, interpret=False) -> dict`` maps input arrays (by name, the
     same dict ``sim.make_inputs`` produces) to the produced output arrays.
     ``source`` is the emitted kernel module text — the debuggable artifact.
     """
@@ -887,7 +945,7 @@ class PallasKernel:
     point_desc: Optional[str] = None
     fusion_shifts: list = field(default_factory=list)
 
-    def __call__(self, arrays, interpret=None):
+    def __call__(self, arrays, interpret=False):
         return self.fn(arrays, interpret=interpret)
 
 
@@ -916,7 +974,7 @@ def lower_program(p: Program, *, block_rows: Optional[int] = None,
     if not nests:
         raise UnlowerableProgram(p.name, [NestContractViolation(
             "empty", "codegen", "program has no loop nests")])
-    plan, soft = _plan_streamed(p, nests, block_rows or DEFAULT_BLOCK_ROWS)
+    plan, soft = _plan_streamed(p, nests, block_rows or SUBLANE_ROWS)
     if plan is not None:
         src, meta = _emit_streamed(p, plan, buffering, dtype)
     else:
@@ -934,18 +992,21 @@ def lower_program(p: Program, *, block_rows: Optional[int] = None,
 
 def _point_block_rows(point) -> Optional[int]:
     """block_rows from a design point: the tile pass marks the outer strip
-    loop with ``tile_block``; fall back to the LoopTile pass config."""
+    loop with ``tile_block``; fall back to the LoopTile pass config.  The
+    tile is rounded up to a multiple of ``SUBLANE_ROWS`` so the row block is
+    one the chip accepts; ``_plan_streamed`` clamps it to the output rows,
+    so an output shorter than that becomes one whole-array block."""
     blocks = [l.tile_block for l in point.program.loops()
               if getattr(l, "tile_block", None)]
-    if blocks:
-        return max(blocks)
-    from .transforms import LoopTile
-    sizes = []
-    for ps in point.passes:
-        if isinstance(ps, LoopTile):
-            sz = ps.seq if ps.seq is not None else tuple(ps.sizes.values())
-            sizes.extend(sz)
-    return max(sizes) if sizes else None
+    if not blocks:
+        from .transforms import LoopTile
+        for ps in point.passes:
+            if isinstance(ps, LoopTile):
+                blocks.extend(ps.seq if ps.seq is not None
+                              else ps.sizes.values())
+    if not blocks:
+        return None
+    return -(-max(blocks) // SUBLANE_ROWS) * SUBLANE_ROWS
 
 
 def emit_pallas(result, point=None, *, buffering: str = "double",

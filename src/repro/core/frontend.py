@@ -43,17 +43,9 @@ import numpy as np
 from .errors import UntraceableFunction
 from .ir import AffExpr, Program, ProgramBuilder, aff
 
-try:  # gated: the frontend is the only core module that needs jax itself
-    import jax
-    import jax.numpy as jnp
-    try:  # jax >= 0.4.35 moves the jaxpr types under jax.extend
-        from jax.extend.core import Literal as _Literal
-    except Exception:  # pragma: no cover - older jax
-        from jax.core import Literal as _Literal
-except ImportError:  # pragma: no cover - container always has jax
-    jax = None
-    jnp = None
-    _Literal = ()
+import jax
+import jax.numpy as jnp
+from jax.extend.core import Literal as _Literal
 
 #: widest reduction/contraction the tracer will unroll into an op chain.
 MAX_UNROLL = 256
@@ -503,15 +495,13 @@ class TracedProgram:
         ``sim.sequential_exec`` and ``fn`` (float64) on the same inputs;
         returns the max relative error, raising AssertionError past
         ``rtol``."""
-        from jax.experimental import enable_x64
-
         from . import sim
 
         inputs = sim.make_inputs(self.program, seed=seed)
         got = sim.sequential_exec(self.program, inputs)
         args = [np.asarray(inputs[n], np.float64).reshape(s)
                 for n, s in zip(self.in_names, self.in_shapes)]
-        with enable_x64():
+        with jax.enable_x64(True):
             want = self.fn(*[jnp.asarray(a) for a in args])
         if not isinstance(want, (tuple, list)):
             want = (want,)
@@ -541,8 +531,6 @@ def trace(fn: Callable, *example_args, name: Optional[str] = None,
           in_names: Optional[Sequence[str]] = None,
           out_names: Optional[Sequence[str]] = None) -> TracedProgram:
     """Trace ``fn`` on ``example_args`` into a :class:`TracedProgram`."""
-    if jax is None:  # pragma: no cover - container always has jax
-        raise ImportError("repro.core.frontend requires jax")
     name = name or getattr(fn, "__name__", "traced")
     closed = jax.make_jaxpr(fn)(*example_args)
     if in_names is None:

@@ -30,8 +30,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, block_k, seq_k):
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(j * block_k, block_k), :]
+        v = v_ref[pl.ds(j * block_k, block_k), :]
         s = q @ k.astype(jnp.float32).T                  # (BQ, BK)
         if causal:
             qpos = qi * BQ + jax.lax.broadcasted_iota(jnp.int32, (BQ, block_k), 0)

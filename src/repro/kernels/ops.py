@@ -1,35 +1,22 @@
-"""jit'd public wrappers for the Pallas kernels + the ILP/kernel bridge.
+"""Public entry points for the hand-written Pallas kernels + the ILP/kernel
+bridge.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU, so
-the same call sites work in both environments.
+Every kernel runs compiled (``interpret=False``) unless the caller asks for
+Pallas interpret mode; a compiled call on a host with no TPU fails instead of
+quietly interpreting.
 
 ``stencil_pipeline`` (and its configuration helpers — the deprecated
 ``stencil_dse_config`` wrapper and the fallback ``ilp_halo_rows``) are
 re-exported from ``repro.kernels.stencil_pipeline`` — that module owns the
-single implementation; this one used to carry a diverging duplicate
-wrapper.  The blessed configuration path is now
+single implementation.  The blessed configuration path is
 ``hls.compile(...).emit_pallas()`` (DESIGN.md §10).
 """
 from __future__ import annotations
 
-from repro.kernels.flash_attention import flash_attention as _fa
-from repro.kernels.stencil_pipeline import (default_interpret as
-                                            _default_interpret,
-                                            ilp_halo_rows, stencil_dse_config,
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.stencil_pipeline import (ilp_halo_rows, stencil_dse_config,
                                             stencil_pipeline)
-from repro.kernels.wkv6 import wkv6 as _wkv
+from repro.kernels.wkv6 import wkv6
 
 __all__ = ["flash_attention", "stencil_pipeline", "stencil_dse_config",
            "ilp_halo_rows", "wkv6"]
-
-
-def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
-                    interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _fa(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-               interpret=interpret)
-
-
-def wkv6(r, k, v, w, u, *, chunk=64, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _wkv(r, k, v, w, u, chunk=chunk, interpret=interpret)

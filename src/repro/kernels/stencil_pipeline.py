@@ -22,7 +22,8 @@ no shifted fusion.  ``stencil_dse_config`` remains as a deprecated wrapper
 (DESIGN.md §6 MIGRATION).
 
 This module owns the single implementation; ``repro.kernels.ops`` re-exports
-it (they used to diverge on the ``interpret`` default).
+it.  ``interpret`` defaults to compiled: a call on a host with no TPU fails
+unless it asks for Pallas interpret mode explicitly.
 """
 from __future__ import annotations
 
@@ -34,17 +35,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def default_interpret() -> bool:
-    """Pallas interpret mode on CPU (this container), compiled on TPU."""
-    return jax.default_backend() != "tpu"
-
-
 def _kernel(img_ref, wx_ref, wy_ref, o_ref, *, block_rows, halo):
     i = pl.program_id(0)
     BR = block_rows
     Wout = o_ref.shape[1]
     # line buffer: BR + halo input rows (the ILP slack), full width
-    rows = pl.load(img_ref, (pl.dslice(i * BR, BR + halo), slice(None)))
+    rows = img_ref[pl.ds(i * BR, BR + halo), :]
     rows = rows.astype(jnp.float32)
     # producer stage: conv-x (3 taps along width)
     wx = wx_ref[...].astype(jnp.float32)
@@ -75,12 +71,10 @@ def _stencil_call(img, wx, wy, *, block_rows, halo, interpret):
 
 
 def stencil_pipeline(img, wx, wy, *, block_rows=None, halo=None,
-                     interpret=None):
+                     interpret=False):
     """img: (H, W); wx, wy: (3,).  Returns conv_y(conv_x(img)) of shape
     (H-2, W-2), computed in one fused pass.  ``block_rows``/``halo`` default
-    to the DSE-derived configuration (``stencil_dse_config``); ``interpret``
-    defaults to True off-TPU."""
-    interpret = default_interpret() if interpret is None else interpret
+    to the DSE-derived configuration (``stencil_dse_config``)."""
     if block_rows is None or halo is None:
         dse_rows, dse_halo = _stencil_codegen_config()
         block_rows = dse_rows if block_rows is None else block_rows

@@ -25,11 +25,11 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, *, chunk, seq):
     tri = jnp.tril(jnp.ones((C, C), jnp.float32), -1)
 
     def body(j, state):
-        sl = (pl.dslice(j * C, C), slice(None))
-        r = pl.load(r_ref, sl).astype(jnp.float32)         # (C, hd)
-        k = pl.load(k_ref, sl).astype(jnp.float32)
-        v = pl.load(v_ref, sl).astype(jnp.float32)
-        w = pl.load(w_ref, sl).astype(jnp.float32)
+        sl = (pl.ds(j * C, C), slice(None))
+        r = r_ref[sl].astype(jnp.float32)                  # (C, hd)
+        k = k_ref[sl].astype(jnp.float32)
+        v = v_ref[sl].astype(jnp.float32)
+        w = w_ref[sl].astype(jnp.float32)
         logw = jnp.log(w)
         cw = jnp.cumsum(logw, axis=0)                      # (C, hd)
         rd = r * jnp.exp(cw - logw)
@@ -43,7 +43,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, *, chunk, seq):
         wtot = jnp.exp(cw[-1])                             # (hd,)
         state1 = state * wtot[:, None] + \
             (k * jnp.exp(cw[-1][None, :] - cw)).T @ v
-        pl.store(o_ref, sl, out.astype(o_ref.dtype))
+        o_ref[sl] = out.astype(o_ref.dtype)
         return state1
 
     state0 = jnp.zeros((hd, hd), jnp.float32)

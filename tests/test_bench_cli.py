@@ -25,3 +25,16 @@ def test_help_lists_suites(capsys):
         main(["--help"])
     assert ei.value.code == 0
     assert "codegen" in capsys.readouterr().out
+
+
+def test_failing_phase_fails_the_run(monkeypatch):
+    """A phase that raises must fail the run instead of printing
+    'unavailable' and exiting 0."""
+    from benchmarks import kernel_bench
+
+    def boom(emit):
+        raise AttributeError("kernel path broken")
+
+    monkeypatch.setattr(kernel_bench, "run", boom)
+    with pytest.raises(AttributeError, match="kernel path broken"):
+        main(["kernels"])

@@ -1,7 +1,7 @@
 """Codegen backend (DESIGN.md §10): generated Pallas kernels vs the
 ``sim.sequential_exec`` oracle.
 
-The float64 lowerings run under ``jax.experimental.enable_x64`` and are
+The float64 lowerings run under ``jax.enable_x64`` and are
 bit-comparable to the float64 numpy oracle (same DAG, same order), so the
 equivalence assertions use rtol=1e-12/atol=0 — anything looser would let a
 structurally wrong window/halo slip through as "close enough".
@@ -12,8 +12,6 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-
-from jax.experimental import enable_x64  # noqa: E402
 
 from repro.core import sim  # noqa: E402
 from repro.core.codegen import PallasKernel, lower_program  # noqa: E402
@@ -30,7 +28,7 @@ def _exact(kernel, p, seed=0):
     exactly on every produced output."""
     inputs = sim.make_inputs(p, seed=seed)
     ref = sim.sequential_exec(p, inputs)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = kernel(inputs, interpret=True)
     for a in kernel.outputs:
         np.testing.assert_allclose(np.asarray(got[a], np.float64), ref[a],
@@ -46,7 +44,7 @@ _CORPUS = {**{k: v for k, v in BENCHMARKS.items()},
            **CHAIN_BENCHMARKS, "fig1_conv_chain": fig1_conv_chain}
 _CORPUS_N = {"optical_flow": 6, "two_mm": 6}
 # nothing in the corpus is structurally rejected anymore: two_mm's 3-deep
-# canonical accumulations now lower in Mode B via a fori_loop left fold
+# canonical accumulations now lower in Mode B via an unrolled left fold
 _EXPECTED_UNLOWERABLE: set = set()
 
 
@@ -209,7 +207,7 @@ def test_property_random_chain(seed):
                       dtype="float64")
     inputs = sim.make_inputs(p, seed=seed)
     ref = sim.sequential_exec(q, inputs)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = k(inputs, interpret=True)
     for a in k.outputs:
         np.testing.assert_allclose(np.asarray(got[a], np.float64), ref[a],
@@ -245,6 +243,12 @@ def test_emit_pallas_from_compile_result():
     if fused:
         kf = r.emit_pallas(fused[0])
         assert kf.fusion_shifts and kf.halo.get("bx", 0) >= 1
+    # every design point maps to a row block the chip accepts: a multiple
+    # of the sublane tile, or one block spanning the whole output
+    from repro.core.codegen import SUBLANE_ROWS
+    for c in r.frontier:
+        kc = r.emit_pallas(c)
+        assert kc.block_rows % SUBLANE_ROWS == 0 or kc.grid == (1,), c.desc
 
 
 def test_emit_pallas_unlowerable_records_diagnostic():
@@ -291,3 +295,28 @@ def test_kernel_source_is_the_artifact():
     assert np.array_equal(
         np.asarray(ns["run"](inputs, interpret=True)[a]),
         np.asarray(k(inputs, interpret=True)[a]))
+
+
+@pytest.mark.parametrize("step", [2, 3])
+@pytest.mark.parametrize("axis", [0, 1])
+def test_strided_helpers_match_numpy(axis, step):
+    """The emitted ``_take`` (strided read without a strided slice) and
+    ``_spread`` (repeat without a lane-splitting reshape) equal numpy's
+    strided slice and repeat, including a window too short for a whole
+    number of phases, which ``_take`` pads."""
+    from repro.core.codegen import _STRIDED_SET_HELPER, _TAKE_HELPER
+
+    ns = {}
+    exec("import jax\nimport jax.numpy as jnp\n" + _TAKE_HELPER
+         + _STRIDED_SET_HELPER, ns)
+    x = np.arange(11 * 13, dtype=np.float32).reshape(11, 13)
+    L = x.shape[axis]
+    for start in range(L):
+        for n in range(1, (L - 1 - start) // step + 2):
+            want = np.take(x, range(start, start + step * (n - 1) + 1, step),
+                           axis=axis)
+            got = np.asarray(ns["_take"](jax.numpy.asarray(x), axis, start,
+                                         n, step))
+            assert np.array_equal(got, want), (start, n)
+    got = np.asarray(ns["_spread"](jax.numpy.asarray(x), axis, step))
+    assert np.array_equal(got, np.repeat(x, step, axis=axis))

@@ -130,14 +130,17 @@ def test_frontier_point_differs_by_tile_size():
     assert min(c.res["bram_bytes"] for c in tiled) < \
         min(c.res["bram_bytes"] for c in untiled)
     # the stencil kernel config reads its block_rows off this exact knob,
-    # via the knee point's generated kernel (emit_pallas)
+    # via the knee point's generated kernel (emit_pallas), which rounds the
+    # tile up to the chip's row tile
+    from repro.core.codegen import SUBLANE_ROWS
     from repro.kernels.stencil_pipeline import (_stencil_codegen_config,
                                                 stencil_config_source)
     block_rows, halo = _stencil_codegen_config()
     assert stencil_config_source() == "dse"
     assert halo == 2
-    assert block_rows in tile_sizes_of(r.knee("latency", "bram",
-                                              among=tiled))
+    knee = r.knee("latency", "bram", among=tiled)
+    assert block_rows in {-(-t // SUBLANE_ROWS) * SUBLANE_ROWS
+                          for t in tile_sizes_of(knee)}
 
 
 def test_metadata_only_candidates_share_pair_enumeration():

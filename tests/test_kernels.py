@@ -64,12 +64,13 @@ def test_stencil_config_from_dse_sweep():
     the 3-tap chain — and must actually have COME from the sweep, not from
     the fallback quietly returning the same values.  The old entry point
     survives as a deprecated wrapper with the same values."""
+    from repro.core.codegen import SUBLANE_ROWS
     from repro.kernels.stencil_pipeline import (_stencil_codegen_config,
                                                 stencil_config_source)
     block_rows, halo = _stencil_codegen_config()
     assert stencil_config_source() == "dse"
     assert halo == 2 == ops.ilp_halo_rows(3)
-    assert block_rows >= 1
+    assert block_rows >= SUBLANE_ROWS and block_rows % SUBLANE_ROWS == 0
     with pytest.warns(DeprecationWarning, match="emit_pallas"):
         assert ops.stencil_dse_config() == (block_rows, halo)
 
