@@ -45,6 +45,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from . import telemetry
 from .errors import Diagnostic
 from .ilp import solve_ilp
 from .ir import (AffExpr, ArithOp, LoadOp, Loop, Program, StoreOp,
@@ -307,11 +308,12 @@ def lint(program: Program) -> list[Diagnostic]:
     severity-first order.  Never raises on malformed programs — every
     problem becomes a :class:`Diagnostic`."""
     out: list[Diagnostic] = []
-    _lint_arrays(program, out)
-    _lint_loops(program, out)
-    _lint_accesses(program, out)
-    _lint_liveness(program, out)
-    _lint_ssa(program, out)
+    with telemetry.span("hls.lint"):
+        _lint_arrays(program, out)
+        _lint_loops(program, out)
+        _lint_accesses(program, out)
+        _lint_liveness(program, out)
+        _lint_ssa(program, out)
     return sorted(out, key=Diagnostic.sort_key)
 
 
@@ -745,7 +747,8 @@ def validate_static(program: Program, schedule, *,
     ``fail_fast=True`` stops at the first problem (used by mutation tests
     where any rejection suffices); the default scans everything so the
     verdict enumerates every violation."""
-    return _Validator(program, schedule, fail_fast).run()
+    with telemetry.span("hls.static_check"):
+        return _Validator(program, schedule, fail_fast).run()
 
 
 # ---------------------------------------------------------------------------

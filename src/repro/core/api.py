@@ -41,7 +41,7 @@ import re
 from dataclasses import dataclass, field, replace as dc_replace
 from typing import Optional, Sequence, Union
 
-from . import faults
+from . import faults, telemetry
 from .autotune import (DSECandidate, DSEResult, MOVE_FAMILIES,
                        PARETO_METRICS, ParetoResult, _degrading,
                        dedupe_diagnostics, measure_candidate,
@@ -482,6 +482,13 @@ def compile(program: Program, spec: Optional[CompileSpec] = None, *,
                                     constraints=constraints,
                                     pipeline=pipeline, combine=combine,
                                     search=search))
+    telemetry.count("hls.compiles")
+    with telemetry.span("hls.compile"):
+        return _compile(program, spec, verbose)
+
+
+def _compile(program: Program, spec: CompileSpec,
+             verbose: bool) -> CompileResult:
     sc = spec.search
     caps: dict[str, float] = {}
     rel: dict[str, float] = {}
@@ -554,14 +561,15 @@ def compile(program: Program, spec: Optional[CompileSpec] = None, *,
                              diagnostics=diagnostics,
                              provenance="degraded" if degraded else "exact")
 
-    r: ParetoResult = pareto_explore(
-        program, caps=caps, rel_caps=rel, moves=sc.moves,
-        unroll_factors=sc.unroll_factors, tile_sizes=sc.tile_sizes,
-        max_candidates=sc.max_candidates, verify=sc.verify, seeds=sc.seeds,
-        mode=spec.target.mode, selector=sc.selector,
-        macro_moves=sc.macro_moves, jobs=sc.jobs,
-        worker_deadline_s=sc.worker_deadline_s,
-        store="auto" if sc.cache else None, verbose=verbose)
+    with telemetry.span("hls.explore"):
+        r: ParetoResult = pareto_explore(
+            program, caps=caps, rel_caps=rel, moves=sc.moves,
+            unroll_factors=sc.unroll_factors, tile_sizes=sc.tile_sizes,
+            max_candidates=sc.max_candidates, verify=sc.verify,
+            seeds=sc.seeds, mode=spec.target.mode, selector=sc.selector,
+            macro_moves=sc.macro_moves, jobs=sc.jobs,
+            worker_deadline_s=sc.worker_deadline_s,
+            store="auto" if sc.cache else None, verbose=verbose)
     best = _select_best(r.frontier, r.baseline, spec)
     if sc.validate:
         validate_candidate(best, sc.seeds)

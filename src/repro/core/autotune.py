@@ -29,7 +29,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence, Union
 
-from . import faults
+from . import faults, telemetry
 from .cache import (CacheStore, fingerprint, get_store, pack_schedule,
                     unpack_schedule)
 from .deps import DepAnalysis
@@ -63,6 +63,12 @@ def _occupancy_floor(loop: Loop, iis: dict[int, int]) -> int:
     return lo
 
 
+def _feasible(p: Program, iis: dict[int, int], dep: DepAnalysis) -> bool:
+    """``feasible``, counted as one probe of the II search."""
+    telemetry.count("hls.ii_probes")
+    return feasible(p, iis, dep)
+
+
 def autotune(p: Program, dep: Optional[DepAnalysis] = None,
              verbose: bool = False) -> dict[int, int]:
     """Return loop uid -> II (programmer-specified IIs respected)."""
@@ -87,7 +93,7 @@ def autotune(p: Program, dep: Optional[DepAnalysis] = None,
 
         def probe(ii: int) -> bool:
             iis[loop.uid] = ii
-            return feasible(p, iis, dep)
+            return _feasible(p, iis, dep)
 
         # ensure hi feasible (double if the conservative bound still fails,
         # e.g. due to cross-nest port serialization pressure)
@@ -107,7 +113,7 @@ def autotune(p: Program, dep: Optional[DepAnalysis] = None,
         if verbose:
             print(f"  autotune: loop {loop.ivname} II={best}")
 
-    if not check_loop_occupancy(p, iis) or not feasible(p, iis, dep):
+    if not check_loop_occupancy(p, iis) or not _feasible(p, iis, dep):
         # unreachable on an exact analysis (the binary search only accepts
         # feasible probes); conservative degraded dependence bounds can in
         # principle leave no feasible II — fail honestly, never return a
@@ -122,8 +128,10 @@ def autotune(p: Program, dep: Optional[DepAnalysis] = None,
 def compile_program(p: Program, verbose: bool = False) -> Schedule:
     """Full pipeline: dependence analysis -> II autotune -> scheduling ILP."""
     dep = DepAnalysis(p)
-    iis = autotune(p, dep, verbose=verbose)
-    s = schedule(p, iis, dep)
+    with telemetry.span("hls.ii_search"):
+        iis = autotune(p, dep, verbose=verbose)
+    with telemetry.span("hls.schedule"):
+        s = schedule(p, iis, dep)
     if not s.feasible:
         raise ScheduleInfeasible("scheduling failed for autotuned IIs")
     return s
@@ -314,10 +322,12 @@ def _probe_candidate_cache(store: Optional[CacheStore], key: Optional[str],
     if entry is None:
         return False, None
     try:
-        return True, _rehydrate_candidate(entry, p, desc, passes, start,
-                                          base_passes, verify, incremental)
+        c = _rehydrate_candidate(entry, p, desc, passes, start, base_passes,
+                                 verify, incremental)
     except (ValueError, KeyError, TypeError):
         return False, None
+    telemetry.count("hls.candidates_cached")
+    return True, c
 
 
 def _degrading(events: Sequence[dict]) -> bool:
@@ -390,36 +400,39 @@ def measure_candidate(p: Program, desc: str, passes: Sequence[Pass], *,
     entry was created, and the entry says so via its ``verified`` flag)."""
     from .dataflow import resources
 
-    start = base if base is not None else p
-    key = None
-    if store is not None:
-        key = _candidate_key(p, tuple(base_passes) + tuple(passes), mode,
-                             incremental, len(tuple(passes)))
-        hit, c = _probe_candidate_cache(store, key, p, desc, passes, start,
-                                        base_passes, verify, incremental)
-        if hit:
-            return c
-    ev0 = faults.event_count()  # degradations recorded while measuring
-    pm = PassManager(passes, verify=verify, seeds=seeds)
-    q = pm.run(start)
-    if passes and (q is start or
-                   (incremental and not pm.reports[-1].changed)):
-        if not _degrading(faults.events_since(ev0)):
-            # a *degraded* no-op verdict (e.g. a conservatively refused
-            # fusion) must not be persisted as the pipeline's truth
-            _store_candidate(store, key, None, verify)
-        return None
-    s = compile_program(q)
-    res = resources(q, s, mode)
-    diags = tuple(faults.events_since(ev0))
-    prov = ("degraded"
-            if s.provenance == "degraded" or _degrading(diags) else "exact")
-    c = DSECandidate(
-        desc=desc or "baseline", passes=tuple(base_passes) + tuple(passes),
-        program=q, schedule=s, latency=s.completion_time(), res=res,
-        within_budget=True, provenance=prov, diags=diags)
-    _store_candidate(store, key, c, verify)
-    return c
+    with telemetry.span("hls.candidate", desc=desc, cached=False) as sp:
+        start = base if base is not None else p
+        key = None
+        if store is not None:
+            key = _candidate_key(p, tuple(base_passes) + tuple(passes), mode,
+                                 incremental, len(tuple(passes)))
+            hit, c = _probe_candidate_cache(store, key, p, desc, passes, start,
+                                            base_passes, verify, incremental)
+            if hit:
+                sp.set(cached=True)
+                return c
+        ev0 = faults.event_count()  # degradations recorded while measuring
+        pm = PassManager(passes, verify=verify, seeds=seeds)
+        q = pm.run(start)
+        if passes and (q is start or
+                       (incremental and not pm.reports[-1].changed)):
+            if not _degrading(faults.events_since(ev0)):
+                # a *degraded* no-op verdict (e.g. a conservatively refused
+                # fusion) must not be persisted as the pipeline's truth
+                _store_candidate(store, key, None, verify)
+            return None
+        s = compile_program(q)
+        with telemetry.span("hls.resources"):
+            res = resources(q, s, mode)
+        diags = tuple(faults.events_since(ev0))
+        prov = ("degraded"
+                if s.provenance == "degraded" or _degrading(diags) else "exact")
+        c = DSECandidate(
+            desc=desc or "baseline", passes=tuple(base_passes) + tuple(passes),
+            program=q, schedule=s, latency=s.completion_time(), res=res,
+            within_budget=True, provenance=prov, diags=diags)
+        _store_candidate(store, key, c, verify)
+        return c
 
 
 def validate_candidate(c: DSECandidate, seeds: Sequence[int] = (0,)) -> None:
@@ -817,7 +830,9 @@ def _measure_wave(wave: list, cur: "DSECandidate", p: Program,
         futs[i] = (sup.submit(make_payload(0)), key, make_payload)
     for i, (fut, key, make_payload) in futs.items():
         full, mvs = wave[i]
-        kind, val = sup.collect(fut, make_payload, full)
+        # the worker's own spans stay in the worker: this one is the wait
+        with telemetry.span("hls.candidate", desc=full, cached=False):
+            kind, val = sup.collect(fut, make_payload, full)
         if kind == "ok":
             c, wevents = val
             if c is None and wevents:

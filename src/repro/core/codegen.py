@@ -58,6 +58,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from . import telemetry
 from .errors import NestContractViolation, UnlowerableProgram
 from .ir import (AffExpr, ArithOp, ConstOp, LoadOp, Loop, Program, StoreOp,
                  nest_shape)
@@ -958,29 +959,34 @@ def lower_program(p: Program, *, block_rows: Optional[int] = None,
     if buffering not in ("double", "single"):
         raise ValueError("buffering must be 'double' or 'single', "
                          f"got {buffering!r}")
-    # A program whose affine accesses can leave their arrays has no faithful
-    # kernel — jnp indexing clamps silently, hiding the bug.  The linter
-    # proves the bounds (or the violation) statically; other lint findings
-    # stay warnings, but OOB is a hard refusal here.
-    from .analysis import lint as _lint
-    oob = [d for d in _lint(p) if d.code in ("oob-read", "oob-write")]
-    if oob:
-        raise UnlowerableProgram(p.name, [
-            NestContractViolation(d.code, "codegen",
-                                  f"{d.where}: {d.detail}") for d in oob])
-    nests, hard = _extract_nests(p)
-    if hard:
-        raise UnlowerableProgram(p.name, hard)
-    if not nests:
-        raise UnlowerableProgram(p.name, [NestContractViolation(
-            "empty", "codegen", "program has no loop nests")])
-    plan, soft = _plan_streamed(p, nests, block_rows or SUBLANE_ROWS)
-    if plan is not None:
-        src, meta = _emit_streamed(p, plan, buffering, dtype)
-    else:
-        src, meta = _emit_whole(p, nests, dtype)
-    ns: dict = {}
-    exec(compile(src, f"<codegen:{p.name}>", "exec"), ns)
+    with telemetry.span("hls.lower"):
+        # A program whose affine accesses can leave their arrays has no
+        # faithful kernel — jnp indexing clamps silently, hiding the bug.
+        # The linter proves the bounds (or the violation) statically; other
+        # lint findings stay warnings, but OOB is a hard refusal here.
+        from .analysis import lint as _lint
+        oob = [d for d in _lint(p) if d.code in ("oob-read", "oob-write")]
+        if oob:
+            raise UnlowerableProgram(p.name, [
+                NestContractViolation(d.code, "codegen",
+                                      f"{d.where}: {d.detail}") for d in oob])
+        with telemetry.span("hls.lower.extract"):
+            nests, hard = _extract_nests(p)
+        if hard:
+            raise UnlowerableProgram(p.name, hard)
+        if not nests:
+            raise UnlowerableProgram(p.name, [NestContractViolation(
+                "empty", "codegen", "program has no loop nests")])
+        with telemetry.span("hls.lower.plan"):
+            plan, soft = _plan_streamed(p, nests, block_rows or SUBLANE_ROWS)
+        with telemetry.span("hls.lower.emit"):
+            if plan is not None:
+                src, meta = _emit_streamed(p, plan, buffering, dtype)
+            else:
+                src, meta = _emit_whole(p, nests, dtype)
+        ns: dict = {}
+        with telemetry.span("hls.lower.exec"):
+            exec(compile(src, f"<codegen:{p.name}>", "exec"), ns)
     return PallasKernel(program_name=p.name, mode=meta["mode"],
                         buffering=buffering if meta["mode"] == "streamed"
                         else "whole", source=src, fn=ns["run"],

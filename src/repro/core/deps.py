@@ -42,7 +42,7 @@ import math
 
 import numpy as np
 
-from . import faults
+from . import faults, telemetry
 from .ilp import solve_ilp
 from .ir import ArrayDecl, LoadOp, Loop, Program, StoreOp, position_keys
 
@@ -361,8 +361,6 @@ class _Pair:
 # up per (src uid, snk uid, kind).
 # ---------------------------------------------------------------------------
 
-DATA_PAIR_ENUM_RUNS = 0   # full (uncached) data-pair enumerations (test probe)
-DATA_PAIR_CACHE_HITS = 0  # enumerations served from the shared cache
 _DATA_PAIR_CACHE: "OrderedDict[str, dict]" = OrderedDict()
 _DATA_PAIR_CACHE_MAX = 64
 
@@ -370,8 +368,12 @@ _DATA_PAIR_CACHE_MAX = 64
 def cache_stats() -> dict:
     """Hit/miss counters and current size of the module-level data-pair
     cache (bounded at ``_DATA_PAIR_CACHE_MAX`` entries with LRU eviction, so
-    long-running serving processes don't grow without limit)."""
-    return {"hits": DATA_PAIR_CACHE_HITS, "misses": DATA_PAIR_ENUM_RUNS,
+    long-running serving processes don't grow without limit).  The counters
+    are ``telemetry``'s ``hls.data_pairs_shared`` (hits) and
+    ``hls.data_pairs_enumerated`` (misses: full enumerations)."""
+    c = telemetry.counters
+    return {"hits": c.get("hls.data_pairs_shared", 0),
+            "misses": c.get("hls.data_pairs_enumerated", 0),
             "entries": len(_DATA_PAIR_CACHE),
             "max_entries": _DATA_PAIR_CACHE_MAX}
 
@@ -408,8 +410,6 @@ class DepAnalysis:
     def __init__(self, p: Program, fastpath: bool = True,
                  crosscheck: bool = False):
         self.p = p
-        self.accesses = collect_accesses(p)
-        self.pos = position_keys(p)
         self.fastpath = fastpath
         self.crosscheck = crosscheck
         self.fallback_cases = 0   # cases the closed form could not take
@@ -423,7 +423,10 @@ class DepAnalysis:
         self._edge_cache: dict = {}
         self._static_edges: Optional[list[DepEdge]] = None
         self._nodes: Optional[list] = None
-        self._pairs: list[_Pair] = self._enumerate_pairs()
+        with telemetry.span("hls.deps"):
+            self.accesses = collect_accesses(p)
+            self.pos = position_keys(p)
+            self._pairs: list[_Pair] = self._enumerate_pairs()
 
     def all_nodes(self) -> list:
         """Every op/loop node, cached (reused across autotuner probes)."""
@@ -472,7 +475,6 @@ class DepAnalysis:
         return cases
 
     def _enumerate_pairs(self) -> list[_Pair]:
-        global DATA_PAIR_ENUM_RUNS, DATA_PAIR_CACHE_HITS
         pairs = []
         by_array: dict[str, list[Access]] = {}
         for a in self.accesses:
@@ -483,13 +485,13 @@ class DepAnalysis:
         key = iteration_space_key(self.p)
         shared = _DATA_PAIR_CACHE.get(key)
         if shared is None:
-            DATA_PAIR_ENUM_RUNS += 1
+            telemetry.count("hls.data_pairs_enumerated")
             shared = {}
             _DATA_PAIR_CACHE[key] = shared
             while len(_DATA_PAIR_CACHE) > _DATA_PAIR_CACHE_MAX:
                 _DATA_PAIR_CACHE.popitem(last=False)
         else:
-            DATA_PAIR_CACHE_HITS += 1
+            telemetry.count("hls.data_pairs_shared")
             _DATA_PAIR_CACHE.move_to_end(key)
 
         for name, accs in by_array.items():
@@ -555,9 +557,12 @@ class DepAnalysis:
             val = _fast_slack_case(la, lb, pfx, carry_level, rows, iis)
             if val is not _FALLBACK:
                 self.fast_cases += 1
+                telemetry.count("hls.dep_cases_closed")
                 if self.crosscheck:
                     deg0 = len(self.degradations)
-                    ref = self._ilp_case_slack(X, Y, carry_level, rows, iis)
+                    with telemetry.span("hls.dep_ilp"):
+                        ref = self._ilp_case_slack(X, Y, carry_level, rows,
+                                                   iis)
                     if len(self.degradations) > deg0:
                         # the ILP reference itself was truncated: its value
                         # is a bound, not a ground truth to compare against
@@ -568,7 +573,9 @@ class DepAnalysis:
                             f"({X.op} -> {Y.op}, carry={carry_level})")
                 return val
             self.fallback_cases += 1
-        return self._ilp_case_slack(X, Y, carry_level, rows, iis)
+            telemetry.count("hls.dep_cases_ilp")
+        with telemetry.span("hls.dep_ilp"):
+            return self._ilp_case_slack(X, Y, carry_level, rows, iis)
 
     def _ilp_case_slack(self, X: Access, Y: Access,
                         carry_level: Optional[int], rows: list,

@@ -46,13 +46,12 @@ from __future__ import annotations
 import copy
 import itertools
 import math
-import time
 from dataclasses import dataclass, replace as dc_replace
 from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from . import faults
+from . import faults, telemetry
 from .ilp import solve_ilp
 from .ir import (AffExpr, ArithOp, ConstOp, LoadOp, Loop, Program, StoreOp,
                  aff, iv, nest_shape, normalize)
@@ -230,7 +229,6 @@ def _param_scalar(v, kind, what: str):
 class PassReport:
     name: str
     changed: bool
-    seconds: float
 
 
 def _fingerprint(p: Program) -> str:
@@ -262,24 +260,23 @@ class PassManager:
         self.reports = []
         cur = p
         for ps in self.passes:
-            t0 = time.perf_counter()
-            before = _fingerprint(cur) if self.verify else None
-            nxt = ps.apply(cur)
-            if self.verify:
-                if _fingerprint(cur) != before:
-                    raise PassVerificationError(
-                        f"pass '{ps.name}' mutated its input program "
-                        "(passes must clone, then rewrite the clone)")
-                if nxt is not cur:  # identical object == proven no-op
-                    try:
-                        differential_check(cur, nxt, seeds=self.seeds)
-                    except AssertionError as e:
+            with telemetry.span("hls.pass", name=ps.name):
+                before = _fingerprint(cur) if self.verify else None
+                nxt = ps.apply(cur)
+                if self.verify:
+                    if _fingerprint(cur) != before:
                         raise PassVerificationError(
-                            f"pass '{ps.name}' changed program semantics: {e}"
-                        ) from e
-            self.reports.append(PassReport(
-                name=ps.name, changed=nxt is not cur,
-                seconds=time.perf_counter() - t0))
+                            f"pass '{ps.name}' mutated its input program "
+                            "(passes must clone, then rewrite the clone)")
+                    if nxt is not cur:  # identical object == proven no-op
+                        try:
+                            differential_check(cur, nxt, seeds=self.seeds)
+                        except AssertionError as e:
+                            raise PassVerificationError(
+                                f"pass '{ps.name}' changed program "
+                                f"semantics: {e}") from e
+            self.reports.append(PassReport(name=ps.name,
+                                           changed=nxt is not cur))
             cur = nxt
         return cur
 
@@ -301,15 +298,16 @@ def differential_check(p: Program, q: Program,
             raise AssertionError(f"array {name} disappeared")
         if tuple(q.arrays[name].shape) != tuple(arr.shape):
             raise AssertionError(f"array {name} changed shape")
-    for seed in seeds:
-        base = make_inputs(p, seed)
-        extra = make_inputs(q, seed + 7919)
-        qin = {**extra, **{k: v.copy() for k, v in base.items()}}
-        out_p = sequential_exec(p, base)
-        out_q = sequential_exec(q, qin)
-        for k in out_p:
-            if not np.allclose(out_p[k], out_q[k], rtol=1e-12, atol=0):
-                raise AssertionError(f"array {k} differs (seed {seed})")
+    with telemetry.span("hls.verify"):
+        for seed in seeds:
+            base = make_inputs(p, seed)
+            extra = make_inputs(q, seed + 7919)
+            qin = {**extra, **{k: v.copy() for k, v in base.items()}}
+            out_p = sequential_exec(p, base)
+            out_q = sequential_exec(q, qin)
+            for k in out_p:
+                if not np.allclose(out_p[k], out_q[k], rtol=1e-12, atol=0):
+                    raise AssertionError(f"array {k} differs (seed {seed})")
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ DEFAULT_TIMEOUT = int(os.environ.get("REPRO_TEST_TIMEOUT", "120"))
 
 # The suite must be hermetic: a warm ~/.cache/repro-hls from an earlier run
 # (or another test) would skip compiles that tests count (e.g. the
-# DATA_PAIR_ENUM_RUNS probes).  The persistent compile cache is therefore
+# hls.data_pairs_enumerated probes).  The persistent compile cache is therefore
 # OFF for every test; dedicated cache tests re-enable it against a tmpdir
 # via monkeypatch (REPRO_HLS_CACHE=1 + REPRO_HLS_CACHE_DIR).
 os.environ["REPRO_HLS_CACHE"] = "0"

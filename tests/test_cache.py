@@ -17,7 +17,8 @@ Covers, per the serving-scale-DSE acceptance criteria:
   * macro-moves reach the blur_chain fuse+tile frontier point in strictly
     fewer compiles than the classic max_candidates=24 search;
   * the hypervolume selector is deterministic and exact on knowns;
-  * deps.cache_stats() exposes the bounded data-pair cache counters.
+  * deps.cache_stats() exposes the bounded data-pair cache counters,
+    read from the process-wide telemetry counters.
 """
 import json
 import multiprocessing
@@ -25,7 +26,7 @@ import os
 
 import pytest
 
-from repro.core import deps, hls
+from repro.core import deps, hls, telemetry
 from repro.core.autotune import (_hv, measure_candidate, pareto_explore)
 from repro.core.cache import (CacheStore, SCHEDULER_SALT, fingerprint,
                               get_store, pack_schedule, program_text,
@@ -355,6 +356,9 @@ def test_deps_cache_stats_counters():
     assert after["hits"] >= mid["hits"] + 1
     assert after["misses"] == mid["misses"]
     assert after["entries"] <= after["max_entries"]
+    # the stats read the process-wide counters
+    assert after["hits"] == telemetry.counters["hls.data_pairs_shared"]
+    assert after["misses"] == telemetry.counters["hls.data_pairs_enumerated"]
 
 
 # ---------------------------------------------------------------------------

@@ -146,20 +146,24 @@ def test_frontier_point_differs_by_tile_size():
 def test_metadata_only_candidates_share_pair_enumeration():
     """ArrayPartition only rewrites array metadata: a DepAnalysis over the
     partitioned clone must reuse the original's data-dependence pair
-    enumeration (probed via the module call counter) — while a transform
-    that changes the iteration space must not."""
-    from repro.core import deps
+    enumeration (probed via the ``hls.data_pairs_enumerated`` counter) —
+    while a transform that changes the iteration space must not."""
+    from repro.core import telemetry
     from repro.core.deps import DepAnalysis
     from repro.core.transforms import ArrayPartition, LoopUnroll
 
     p = harris(6, storage="bram")
-    before = deps.DATA_PAIR_ENUM_RUNS
+
+    def runs():
+        return telemetry.counters.get("hls.data_pairs_enumerated", 0)
+
+    before = runs()
     d1 = DepAnalysis(p)
-    assert deps.DATA_PAIR_ENUM_RUNS == before + 1
+    assert runs() == before + 1
 
     q = ArrayPartition().apply(p)
     d2 = DepAnalysis(q)
-    assert deps.DATA_PAIR_ENUM_RUNS == before + 1, \
+    assert runs() == before + 1, \
         "metadata-only clone re-ran pair enumeration"
     # the shared half must produce identical data pairs (kinds + uids)
     data = lambda d: sorted((pr.X.uid, pr.Y.uid, pr.kind) for pr in d._pairs
@@ -168,12 +172,12 @@ def test_metadata_only_candidates_share_pair_enumeration():
 
     # re-analyzing the SAME program also shares
     DepAnalysis(p)
-    assert deps.DATA_PAIR_ENUM_RUNS == before + 1
+    assert runs() == before + 1
 
     # an iteration-space change must NOT share
     u = LoopUnroll(2).apply(p)
     DepAnalysis(u)
-    assert deps.DATA_PAIR_ENUM_RUNS == before + 2
+    assert runs() == before + 2
 
     # and the shared analyses still compile to working schedules
     s = compile_program(q)
