@@ -9,14 +9,18 @@ reads it.  Two kinds of events matter:
   Mosaic kernel that ``pallas_call`` becomes), found by op kind and not by
   the kernel's name; every other op is glue.  An op whose event holds
   others (a ``while`` loop's) is counted through the ops it holds.
-* host spans: the benchmark's own annotations, named ``bench.<what>``, on
-  the host plane.  ``bench.stretch`` bounds the traced stretch; the others
-  name what the host was doing in each idle gap of the device.
+* host spans: the benchmark's own annotations, named ``bench.<what>``, and
+  the program's, named ``hls.<what>`` (``repro.core.telemetry``, recorded
+  only inside ``telemetry.recording()``), on the host plane.
+  ``bench.stretch`` bounds the traced stretch.  The ``bench.*`` spans name
+  the longest idle gaps of the device (``idle_gaps``); all of them, the
+  innermost open one, split its whole idle time (``idle_self``).
 
 The profiler puts both on one clock, in nanoseconds.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -25,6 +29,8 @@ from dataclasses import dataclass, field
 DEVICE_PLANE = re.compile(r"/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 STRETCH = "bench.stretch"
+HOST_SPANS = ("bench.", "hls.")
+OUTSIDE = "host outside bench spans"
 TOP = 10
 
 
@@ -52,6 +58,7 @@ class Summary:
     window_s: float        # length of the stretch
     device_ops: list = field(default_factory=list)  # [[name, s]] top 10
     idle_gaps: list = field(default_factory=list)   # [[host span, s]]
+    idle_self: list = field(default_factory=list)   # [[host span, s]] top 10
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -94,7 +101,8 @@ def short_name(name: str) -> str:
 
 
 def load(path: str) -> tuple[list[Op], list[Span]]:
-    """The device ops and the benchmark's host spans of one trace file."""
+    """The device ops and the host spans (the benchmark's and the
+    program's) of one trace file."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     ops: list[Op] = []
@@ -109,7 +117,7 @@ def load(path: str) -> tuple[list[Op], list[Span]]:
                                   is_kernel(ev.name)))
             elif not m and plane.name.startswith("/host:"):
                 for ev in line.events:
-                    if ev.name.startswith("bench."):
+                    if ev.name.startswith(HOST_SPANS):
                         spans.append(Span(ev.name, ev.start_ns,
                                           ev.duration_ns))
     return ops, spans
@@ -147,9 +155,10 @@ def leaves(ops: list[Op]) -> list[Op]:
 
 def reduce(ops: list[Op], spans: list[Span], n_devices: int) -> Summary:
     """Kernel, glue and busy time of the ops inside the stretch, the
-    stretch's length, the ops that took most time and the longest idle
-    gaps, each named by the host span that overlaps it most.  Kernel and
-    glue time count only the ops that hold no other (``leaves``)."""
+    stretch's length, the ops that took most time, the longest idle gaps,
+    each named by the ``bench.*`` span that overlaps it most, and the idle
+    time by the innermost host span open over it (``idle_self``).  Kernel
+    and glue time count only the ops that hold no other (``leaves``)."""
     st = [s for s in spans if s.name == STRETCH]
     if len(st) != 1:
         raise ValueError(f"expected one {STRETCH} span, found {len(st)}")
@@ -171,21 +180,46 @@ def reduce(ops: list[Op], spans: list[Span], n_devices: int) -> Summary:
         gaps += [(edges[i + 1] - edges[i], edges[i], edges[i + 1])
                  for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
     host = [s for s in spans if s.name != STRETCH]
+    ours = [s for s in host if s.name.startswith("bench.")]
     named = []
     for length, g0, g1 in sorted(gaps, key=lambda g: (-g[0], g[1]))[:TOP]:
-        best, cover = "host outside bench spans", 0.0
-        for s in host:
+        best, cover = OUTSIDE, 0.0
+        for s in ours:
             c = min(g1, s.start_ns + s.dur_ns) - max(g0, s.start_ns)
             if c > cover:
                 best, cover = s.name, c
         named.append([best, length * 1e-9])
     n = max(n_devices, 1)
+    by_span = idle_by_innermost(gaps, host)
     return Summary(
         kernel_s=kernel * 1e-9 / n, glue_s=glue * 1e-9 / n,
         busy_s=busy * 1e-9 / n, window_s=(w1 - w0) * 1e-9,
         device_ops=[[k, v * 1e-9] for k, v in
                     sorted(per_op.items(), key=lambda kv: -kv[1])[:TOP]],
-        idle_gaps=named)
+        idle_gaps=named,
+        idle_self=[[k, v * 1e-9 / n] for k, v in
+                   sorted(by_span.items(), key=lambda kv: -kv[1])[:TOP]])
+
+
+def idle_by_innermost(gaps: list[tuple[float, float, float]],
+                      spans: list[Span]) -> dict[str, float]:
+    """The idle time of ``gaps`` ((length, start, end), ns) by the
+    innermost of ``spans`` open over it: of the spans that cover an
+    instant, the one opened last (the shorter at a tie).  Time that no
+    span covers goes to ``OUTSIDE``."""
+    edges = sorted({x for s in spans for x in (s.start_ns,
+                                                s.start_ns + s.dur_ns)})
+    out: dict[str, float] = {}
+    for _, g0, g1 in gaps:
+        cuts = ([g0] + edges[bisect.bisect_right(edges, g0):
+                             bisect.bisect_left(edges, g1)] + [g1])
+        for a, b in zip(cuts, cuts[1:]):
+            open_ = [s for s in spans
+                     if s.start_ns <= a and b <= s.start_ns + s.dur_ns]
+            name = (max(open_, key=lambda s: (s.start_ns, -s.dur_ns)).name
+                    if open_ else OUTSIDE)
+            out[name] = out.get(name, 0.0) + (b - a)
+    return out
 
 
 def summarize(path: str, n_devices: int) -> Summary:

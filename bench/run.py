@@ -57,6 +57,7 @@ class Readings:
     ops: int            # the algorithm's operations per call
     nbytes: int         # the algorithm's HBM bytes per call
     peak: object        # peaks.Peak
+    program: dict | None = None  # the program's spans (program_readings)
 
 
 def configure_jax_cache() -> None:
@@ -82,6 +83,36 @@ def _no_cache_writes():
         yield
     finally:
         jax.config.update(key, old)
+
+
+@contextmanager
+def _program_record(on: bool):
+    """The program's own span record (``telemetry.recording()``) around
+    the body where ``on``; yields None where off or where the program
+    keeps no telemetry."""
+    telemetry = None
+    if on:
+        try:
+            from repro.core import telemetry
+        except ImportError:
+            pass
+    if telemetry is None:
+        yield None
+        return
+    with telemetry.recording() as rec:
+        yield rec
+
+
+def program_readings(rec) -> dict | None:
+    """``telemetry.summary`` of a record, with ``compiles``: the number of
+    root ``hls.compile`` spans, the divisor of the span metrics."""
+    if rec is None:
+        return None
+    from repro.core import telemetry
+    out = telemetry.summary(rec)
+    out["compiles"] = sum(1 for s in rec.spans if s.name == "hls.compile"
+                          and s.parent is None and s.end_ns is not None)
+    return out
 
 
 def _lowering(kernel, knee) -> dict:
@@ -128,58 +159,68 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool,
     reservoir = drive.Reservoir(mix["sample"], seed)
     window = drive.Spans()
     produced = {}
-    if mix["kind"] == "stream":
-        setup_s = time.perf_counter() - t0
-        n, secs = drive.stream(f, frames, seconds, mix["in_flight"],
-                               reservoir)
-        produced["call_us"] = secs / (n * (batch or 1)) * 1e6
-        attempted, failed = n * (batch or 1), 0
-    elif mix["kind"] == "recompile":
+    if mix["kind"] == "recompile":
         # a step that compiles the nominal program once more keeps the
         # window's first compile from paying what only a first one pays
         with setup("warm_compile"):
             drive.compile_once(cfg, mod, nominal, frames[0], drive.Spans(),
                                interpret)
-        setup_s = time.perf_counter() - t0
-        rng = np.random.default_rng([seed, 1])
-        with _no_cache_writes():
-            n, failed, secs = drive.recompile(cfg, mod, frames[0], seconds,
-                                              rng, reservoir, window,
-                                              interpret)
-        produced["compile_s"] = secs / n
-        attempted = n
-    else:
+    elif mix["kind"] != "stream":
         raise ValueError(f"unknown traffic kind {mix['kind']!r}")
-    produced["setup_s"] = setup_s
-    info["setup_phases_s"] = {k: sum(v) for k, v in setup.durations.items()}
-    info["window"] = {"attempted": attempted, "seconds": secs}
+    setup_s = time.perf_counter() - t0
 
-    mem = [d.memory_stats() for d in devices]
-    memory_peak = max((m or {}).get("peak_bytes_in_use", 0) for m in mem)
+    # a traced run of the recompile mix records the program's spans over
+    # its window and its traced stretch; an untraced run never does, so
+    # nothing it reports pays for them
+    with _program_record(trace and mix["kind"] == "recompile") as rec:
+        if mix["kind"] == "stream":
+            n, secs = drive.stream(f, frames, seconds, mix["in_flight"],
+                                   reservoir)
+            produced["call_us"] = secs / (n * (batch or 1)) * 1e6
+            attempted, failed = n * (batch or 1), 0
+        else:
+            rng = np.random.default_rng([seed, 1])
+            with _no_cache_writes():
+                n, failed, secs = drive.recompile(cfg, mod, frames[0],
+                                                  seconds, rng, reservoir,
+                                                  window, interpret)
+            produced["compile_s"] = secs / n
+            attempted = n
+        produced["setup_s"] = setup_s
+        info["setup_phases_s"] = {k: sum(v)
+                                  for k, v in setup.durations.items()}
+        info["window"] = {"attempted": attempted, "seconds": secs}
 
-    summary = None
-    calls = 0
-    if trace:
-        from bench import devtrace
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        stretch = drive.Spans()
-        with jax.profiler.trace(TRACE_DIR, profiler_options=opts):
-            if mix["kind"] == "stream":
-                drive.stream_stretch(f, frames, mix["trace_calls"],
-                                     mix["in_flight"], stretch)
-                calls = mix["trace_calls"] * (batch or 1)
-            else:
-                calls = mix["trace_compiles"]
-                rng = np.random.default_rng([seed, 2])
-                with _no_cache_writes(), stretch("stretch"):
-                    for _ in range(calls):
-                        jax.block_until_ready(drive.edit_compile_run(
-                            cfg, mod, mod.consts(cfg, rng), frames[0],
-                            stretch, interpret))
-        summary = devtrace.summarize(devtrace.find_xplane(TRACE_DIR),
-                                     n_devices=len(devices))
+        mem = [d.memory_stats() for d in devices]
+        memory_peak = max((m or {}).get("peak_bytes_in_use", 0) for m in mem)
+
+        summary = None
+        calls = 0
+        if trace:
+            from bench import devtrace
+            shutil.rmtree(TRACE_DIR, ignore_errors=True)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            stretch = drive.Spans()
+            with jax.profiler.trace(TRACE_DIR, profiler_options=opts):
+                if mix["kind"] == "stream":
+                    drive.stream_stretch(f, frames, mix["trace_calls"],
+                                         mix["in_flight"], stretch)
+                    calls = mix["trace_calls"] * (batch or 1)
+                else:
+                    calls = mix["trace_compiles"]
+                    rng = np.random.default_rng([seed, 2])
+                    with _no_cache_writes(), stretch("stretch"):
+                        for _ in range(calls):
+                            jax.block_until_ready(drive.edit_compile_run(
+                                cfg, mod, mod.consts(cfg, rng), frames[0],
+                                stretch, interpret))
+            summary = devtrace.summarize(devtrace.find_xplane(TRACE_DIR),
+                                         n_devices=len(devices))
+    program = program_readings(rec)
+    if program is not None:
+        info["program_spans"] = {k: v["total_s"]
+                                 for k, v in program["spans"].items()}
 
     # the check, once the window has closed and the peak has been read:
     # every frame of each sampled answer against the reference
@@ -209,7 +250,8 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool,
         readings = Readings(calls=calls, trace=summary,
                             spans=dict(window.durations), ops=ops,
                             nbytes=nbytes,
-                            peak=peaks.peak_of(devices[0].device_kind))
+                            peak=peaks.peak_of(devices[0].device_kind),
+                            program=program)
         metrics = {}
         for m in bspec.per_layer_of(spec, cell["name"]):
             v = bspec.load_reader(m["name"])(readings)
@@ -234,7 +276,8 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool,
         result["device"]["busy_s"] = summary.busy_s
         result["device"]["window_s"] = summary.window_s
         result["breakdown"] = {"device_ops": summary.device_ops,
-                               "idle_gaps": summary.idle_gaps}
+                               "idle_gaps": summary.idle_gaps,
+                               "idle_self": summary.idle_self}
     result["checks"] = {"rel_err": {"value": worst, "limit": limit}}
     return result, info
 

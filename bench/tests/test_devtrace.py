@@ -29,6 +29,46 @@ def test_reduce_hand_made():
                            ["host outside bench spans", pytest.approx(100e-9)]]
 
 
+def test_idle_self_goes_to_the_innermost_open_span():
+    """The idle time by the innermost host span open over it, the
+    program's spans among them; the longest gaps are still named by the
+    benchmark's spans alone."""
+    spans = [Span("bench.stretch", 0, 1000),
+             Span("bench.dse", 0, 800), Span("hls.compile", 100, 600),
+             Span("hls.dep_ilp", 200, 100), Span("hls.dep_ilp", 400, 50),
+             Span("bench.run", 800, 100), Span("hls.lower", 920, 80)]
+    ops = [Op(0, "kernel", 850, 50, True)]
+    s = devtrace.reduce(ops, spans, n_devices=1)
+    # gaps [0, 850) and [900, 1000)
+    assert s.idle_gaps == [
+        ["bench.dse", pytest.approx(850e-9)],
+        [devtrace.OUTSIDE, pytest.approx(100e-9)]]
+    assert s.idle_self == [
+        ["hls.compile", pytest.approx(450e-9)],
+        ["bench.dse", pytest.approx(200e-9)],
+        ["hls.dep_ilp", pytest.approx(150e-9)],
+        ["hls.lower", pytest.approx(80e-9)],
+        ["bench.run", pytest.approx(50e-9)],
+        [devtrace.OUTSIDE, pytest.approx(20e-9)]]
+    assert sum(v for _, v in s.idle_self) == pytest.approx(
+        s.window_s - s.busy_s)
+
+
+def test_load_keeps_the_program_spans(tmp_path):
+    """A trace taken here on the CPU: the host spans of the benchmark and
+    of the program are kept, others are not."""
+    import jax
+    import jax.numpy as jnp
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench.stretch"):
+            with jax.profiler.TraceAnnotation("hls.dep_ilp"):
+                jax.block_until_ready(jnp.ones(8) + 1)
+            with jax.profiler.TraceAnnotation("other.span"):
+                pass
+    _, spans = devtrace.load(devtrace.find_xplane(str(tmp_path)))
+    assert {s.name for s in spans} == {"bench.stretch", "hls.dep_ilp"}
+
+
 def test_reduce_counts_a_loop_body_once():
     """A ``while`` event spans the ops of its body: kernel and glue count
     the body's ops alone, busy time the union of all."""
@@ -95,8 +135,21 @@ def test_recorded_stream_trace():
     assert s.window_s == pytest.approx(0.048312499, rel=1e-9)
     assert [n for n, _ in s.device_ops] == ["custom-call %_unknown_.1",
                                             "copy %copy"]
-    assert s.idle_gaps[0] == ["bench.wait", pytest.approx(0.001654679)]
-    assert {n for n, _ in s.idle_gaps[1:]} == {"bench.dispatch"}
+    assert s.idle_gaps == [["bench.wait", 0.001654679],
+                           ["bench.dispatch", 0.000292971],
+                           ["bench.dispatch", 0.000275564],
+                           ["bench.dispatch", 0.00027450900000000004],
+                           ["bench.dispatch", 0.000271584],
+                           ["bench.dispatch", 0.00026734],
+                           ["bench.dispatch", 0.000266648],
+                           ["bench.dispatch", 0.00026531300000000004],
+                           ["bench.dispatch", 0.000257076],
+                           ["bench.dispatch", 0.000256603]]
+    assert s.idle_self == [["bench.dispatch", pytest.approx(0.031797192)],
+                           ["bench.wait", pytest.approx(0.004675581)],
+                           [devtrace.OUTSIDE, pytest.approx(0.00246079)]]
+    assert sum(v for _, v in s.idle_self) == pytest.approx(
+        s.window_s - s.busy_s, rel=1e-9)
     read = {m: bspec.load_reader(m)(r) for m in
             ("kernel_us", "glue_us", "generated_run_roofline", "idle_share")}
     assert read["kernel_us"] == pytest.approx(33.840635, rel=1e-9)
@@ -115,7 +168,13 @@ def test_recorded_recompile_trace():
     assert s.kernel_s == pytest.approx(3.4801e-05, rel=1e-9)
     assert s.busy_s == pytest.approx(4.8259e-05, rel=1e-9)
     assert s.window_s == pytest.approx(0.682559773, rel=1e-9)
-    assert s.idle_gaps[0] == ["bench.dse", pytest.approx(0.681470202)]
+    assert s.idle_gaps == [["bench.dse", 0.6814702020000001],
+                           ["bench.run", 0.001041311], ["bench.run", 1e-09]]
+    assert s.idle_self == [["bench.dse", pytest.approx(0.559657538)],
+                           ["bench.xla_compile", pytest.approx(0.119132193)],
+                           ["bench.run", pytest.approx(0.002163061)],
+                           ["bench.lower", pytest.approx(0.001015641)],
+                           [devtrace.OUTSIDE, pytest.approx(0.000543081)]]
 
 
 @pytest.mark.parametrize("name,kind", [
