@@ -26,9 +26,16 @@ remains is a box-constrained integer program whose equality rows nearly
 always touch one variable (pin it: divisibility + bounds check) or two
 (a 2-var linear Diophantine equation: GCD feasibility, then minimize a
 linear objective over an interval of the solution parameter).  These are
-solved in closed form; only genuinely coupled systems (a residual component
-with >=3 variables or >=3 equations) fall back to branch-and-bound
-``solve_ilp``.  A crucial corollary: the *feasible region* of every case is
+solved in closed form.  A residual component beyond that (>=3 variables or
+>=3 equations, as a tiled index ``2*t + b`` gives) is branched on: pin its
+variable with the narrowest box to each of its values and solve each branch
+again in closed form, keeping the least.  The enumeration is exhaustive over
+a finite box, so the result is the ILP's optimum; the budget is
+multiplicative (a branch of n values passes ``budget // n`` down), so one
+case never solves more than ``_BRANCH_CAP`` leaves.  Only components past
+that budget fall back to branch-and-bound ``solve_ilp``.  The counter
+``hls.dep_cases_branched`` counts the cases the closed form took only by
+branching.  A crucial corollary: the *feasible region* of every case is
 II-independent (IIs only weight the objective), so pair/case feasibility is
 decided once at construction and never re-examined across autotuner probes.
 """
@@ -116,6 +123,10 @@ def _common_prefix_len(a: tuple[Loop, ...], b: tuple[Loop, ...]) -> int:
 
 _FALLBACK = object()  # sentinel: case not separable, use the ILP
 
+# Closed-form leaves one call of ``_solve_separable`` may enumerate by
+# branching before it leaves a coupled component to the ILP.
+_BRANCH_CAP = 64
+
 
 def _ext_gcd(a: int, b: int) -> tuple[int, int, int]:
     """(g, p, q) with a*p + b*q == g == gcd(a, b) (g >= 0)."""
@@ -164,16 +175,54 @@ def _min_diophantine_2var(a: int, b: int, e: int,
     return cu * (u0 + su * t) + cv * (v0 + sv * t)
 
 
-def _solve_separable(vars: dict, rows: list):
+def _closed_component(vars: dict, crows: list):
+    """Closed form of one residual component: two variables under one row
+    (a 2-var Diophantine equation) or two rows (Cramer, or one row when
+    proportional).  Returns the component's optimum, None (infeasible), or
+    _FALLBACK when the component is beyond the closed form."""
+    cvars = sorted({v for coeffs, _ in crows for v in coeffs})
+    if len(cvars) != 2:
+        return _FALLBACK
+    u, v = cvars
+    if len(crows) == 2:
+        (c1, e1), (c2, e2) = crows
+        a1, b1 = c1.get(u, 0), c1.get(v, 0)
+        a2, b2 = c2.get(u, 0), c2.get(v, 0)
+        det = a1 * b2 - a2 * b1
+        if det != 0:
+            un, vn = e1 * b2 - e2 * b1, a1 * e2 - a2 * e1
+            if un % det or vn % det:
+                return None
+            uu, vv = un // det, vn // det
+            if not (vars[u][0] <= uu <= vars[u][1] and
+                    vars[v][0] <= vv <= vars[v][1]):
+                return None
+            return vars[u][2] * uu + vars[v][2] * vv
+        # proportional LHS: consistent -> one row; else infeasible
+        if a1 * e2 != a2 * e1 or b1 * e2 != b2 * e1:
+            return None
+        crows = [(c1, e1)]
+    if len(crows) != 1:
+        return _FALLBACK
+    coeffs, rhs = crows[0]
+    return _min_diophantine_2var(coeffs[u], coeffs[v], rhs,
+                                 vars[u][0], vars[u][1],
+                                 vars[v][0], vars[v][1],
+                                 vars[u][2], vars[v][2])
+
+
+def _solve_separable(vars: dict, rows: list, budget: int = _BRANCH_CAP):
     """min sum c_v * v over integer vars with box bounds and equality rows.
 
     ``vars``: vid -> (lo, hi, c).  ``rows``: list of (dict vid->coeff, rhs).
-    Returns the optimum (int), None (infeasible), or _FALLBACK when a
-    residual component is not closed-form solvable.
+    Returns ``(value, branched)``: the optimum (int), None (infeasible), or
+    _FALLBACK when a residual component is beyond the closed form even by
+    branching within ``budget`` leaves; ``branched`` says whether the value
+    took a branch.
     """
     for lo, hi, _ in vars.values():
         if lo > hi:
-            return None
+            return None, False
 
     fixed: dict = {}
     rows = [(dict(coeffs), rhs) for coeffs, rhs in rows]
@@ -188,7 +237,7 @@ def _solve_separable(vars: dict, rows: list):
                     nc[v] = a
             if not nc:
                 if rhs != 0:
-                    return None
+                    return None, False
                 continue
             nrows.append((nc, rhs))
         rows = nrows
@@ -198,14 +247,14 @@ def _solve_separable(vars: dict, rows: list):
                 (v, a), = coeffs.items()
                 if v in fixed:
                     if a * fixed[v] != rhs:
-                        return None
+                        return None, False
                     continue
                 if rhs % a:
-                    return None
+                    return None, False
                 val = rhs // a
                 lo, hi, _ = vars[v]
                 if not (lo <= val <= hi):
-                    return None
+                    return None, False
                 fixed[v] = val
                 newly = True
         if not newly:
@@ -214,65 +263,57 @@ def _solve_separable(vars: dict, rows: list):
     total = sum(vars[v][2] * val for v, val in fixed.items())
 
     # connected components over the residual rows (each row now has >= 2
-    # vars, singletons were eliminated).  A row bridging two existing
-    # components implies >= 4 coupled variables — beyond the closed form —
-    # so bail out immediately instead of merging.
+    # vars, singletons were eliminated); a row bridging two components
+    # merges them
     comp: dict = {}
     comp_rows: dict[int, list] = {}
-    next_root = 0
-    for coeffs, rhs in rows:
-        roots = {comp[v] for v in coeffs if v in comp}
-        if len(roots) > 1:
-            return _FALLBACK
-        if roots:
-            root = roots.pop()
-        else:
-            root = next_root
-            next_root += 1
-        for v in coeffs:
-            comp[v] = root
-        comp_rows.setdefault(root, []).append((coeffs, rhs))
+    for i, row in enumerate(rows):
+        crows = [row]
+        for root in {comp[v] for v in row[0] if v in comp}:
+            crows += comp_rows.pop(root)
+        comp_rows[i] = crows
+        for coeffs, _ in crows:
+            for v in coeffs:
+                comp[v] = i
 
+    hard_rows: list = []
     for crows in comp_rows.values():
-        cvars = sorted({v for coeffs, _ in crows for v in coeffs})
-        if len(cvars) != 2:
-            return _FALLBACK
-        u, v = cvars
-        if len(crows) == 2:
-            (c1, e1), (c2, e2) = crows
-            a1, b1 = c1.get(u, 0), c1.get(v, 0)
-            a2, b2 = c2.get(u, 0), c2.get(v, 0)
-            det = a1 * b2 - a2 * b1
-            if det != 0:
-                un, vn = e1 * b2 - e2 * b1, a1 * e2 - a2 * e1
-                if un % det or vn % det:
-                    return None
-                uu, vv = un // det, vn // det
-                if not (vars[u][0] <= uu <= vars[u][1] and
-                        vars[v][0] <= vv <= vars[v][1]):
-                    return None
-                total += vars[u][2] * uu + vars[v][2] * vv
-                continue
-            # proportional LHS: consistent -> one row; else infeasible
-            if a1 * e2 != a2 * e1 or b1 * e2 != b2 * e1:
-                return None
-            crows = [(c1, e1)]
-        if len(crows) != 1:
-            return _FALLBACK
-        coeffs, rhs = crows[0]
-        val = _min_diophantine_2var(coeffs[u], coeffs[v], rhs,
-                                    vars[u][0], vars[u][1],
-                                    vars[v][0], vars[v][1],
-                                    vars[u][2], vars[v][2])
+        val = _closed_component(vars, crows)
         if val is None:
-            return None
-        total += val
+            return None, False
+        if val is _FALLBACK:
+            hard_rows += crows
+        else:
+            total += val
 
     for v, (lo, hi, c) in vars.items():
         if v in fixed or v in comp:
             continue
         total += c * lo if c >= 0 else c * hi
-    return total
+    if not hard_rows:
+        return total, False
+
+    # Branch on the narrowest box of the components beyond the closed
+    # form: pin each of its values and solve the rest again.  Exhaustive
+    # over a finite box, so the minimum over the branches is exact; a
+    # branch of n values passes budget // n down, so one call never
+    # solves more than ``budget`` leaves.
+    sub = {v: vars[v] for coeffs, _ in hard_rows for v in coeffs}
+    pivot = min(sorted(sub), key=lambda v: sub[v][1] - sub[v][0])
+    lo, hi, c = sub[pivot]
+    n = hi - lo + 1
+    if n > budget:
+        return _FALLBACK, False
+    best = None
+    for val in range(lo, hi + 1):
+        sub[pivot] = (val, val, c)
+        r, _ = _solve_separable(sub, hard_rows + [({pivot: 1}, val)],
+                                budget // n)
+        if r is _FALLBACK:
+            return _FALLBACK, False
+        if r is not None and (best is None or r < best):
+            best = r
+    return (None if best is None else total + best), True
 
 
 def _fast_slack_case(la: tuple[Loop, ...], lb: tuple[Loop, ...], pfx: int,
@@ -281,8 +322,9 @@ def _fast_slack_case(la: tuple[Loop, ...], lb: tuple[Loop, ...], pfx: int,
     """Closed-form solve of one happens-before case.
 
     ``rows`` are the address-equality rows over columns x_0..x_{nx-1},
-    y_0..y_{ny-1} (source / sink iteration vectors).  Returns the minimum
-    slack (int), None (case infeasible), or _FALLBACK.
+    y_0..y_{ny-1} (source / sink iteration vectors).  Returns
+    ``(slack, branched)`` as ``_solve_separable`` does: the minimum slack
+    (int), None (case infeasible), or _FALLBACK.
     """
     nx, ny = len(la), len(lb)
     P = carry_level if carry_level is not None else pfx
@@ -297,7 +339,8 @@ def _fast_slack_case(la: tuple[Loop, ...], lb: tuple[Loop, ...], pfx: int,
         for k in range(P, pfx):  # common suffix: d_k = y_k - x_k
             cx, cy = coeffs.get(k, 0), coeffs.get(nx + k, 0)
             if cx != -cy:
-                return _FALLBACK  # not diagonal-coupled; keep the ILP exact
+                # not diagonal-coupled; keep the ILP exact
+                return _FALLBACK, False
             if cy:
                 nc[("d", k)] = cy
         for i in range(pfx, nx):
@@ -554,10 +597,13 @@ class DepAnalysis:
         la, lb = X.ancestors, Y.ancestors
         pfx = _common_prefix_len(la, lb)
         if self.fastpath:
-            val = _fast_slack_case(la, lb, pfx, carry_level, rows, iis)
+            val, branched = _fast_slack_case(la, lb, pfx, carry_level, rows,
+                                             iis)
             if val is not _FALLBACK:
                 self.fast_cases += 1
                 telemetry.count("hls.dep_cases_closed")
+                if branched:
+                    telemetry.count("hls.dep_cases_branched")
                 if self.crosscheck:
                     deg0 = len(self.degradations)
                     with telemetry.span("hls.dep_ilp"):

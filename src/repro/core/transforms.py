@@ -815,8 +815,9 @@ def _max_dep_distance(opA, opB, loopsA: list[Loop], loopsB: list[Loop],
     (``va[k] - vb[k] == d_k``), which is how the lexicographic maximization
     proceeds level by level.  Returns None when the accesses never alias
     under the pinned prefix (no constraint).  Solved closed-form via the
-    deps.py separable solver whenever the address system decomposes;
-    genuinely coupled systems fall back to the branch-and-bound ILP.
+    deps.py separable solver whenever the address system decomposes or
+    closes by branching on a narrow box; coupled systems past its branching
+    budget fall back to the branch-and-bound ILP.
     Raises TransformError when neither resolves.
     """
     from .deps import _FALLBACK as _SEP_FALLBACK, _solve_separable
@@ -844,7 +845,7 @@ def _max_dep_distance(opA, opB, loopsA: list[Loop], loopsB: list[Loop],
                      eb.const - ea.const))
     for lvl, dist in fixed:  # va[lvl] - vb[lvl] == dist
         rows.append(({("x", lvl): 1, ("y", lvl): -1}, dist))
-    r = _solve_separable(vars, rows)
+    r, _ = _solve_separable(vars, rows)
     if r is None:
         return None
     if r is not _SEP_FALLBACK:

@@ -54,3 +54,25 @@ def _timeout_guard(request):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old)
+
+
+@pytest.fixture
+def wide_coupled_program():
+    """Two nests with no common loop, writing and then reading a 1-D array
+    at ``i + j``: the dependence case couples four variables, each with a
+    box of 70 values, wider than the closed form's branching budget
+    (``deps._BRANCH_CAP``), so the case stays with the ILP."""
+    from repro.core.ir import ProgramBuilder
+
+    n = 70
+    b = ProgramBuilder("wide_coupled")
+    b.array("X", (n, n), is_arg=True)
+    b.array("A", (2 * n,))
+    b.array("Y", (n, n), is_arg=True)
+    with b.loop("i", 0, n) as i:
+        with b.loop("j", 0, n) as j:
+            b.store("A", b.load("X", i, j), i + j)
+    with b.loop("k", 0, n) as k:
+        with b.loop("l", 0, n) as l:
+            b.store("Y", b.load("A", k + l), k, l)
+    return b.build()

@@ -7,9 +7,13 @@ II assignments the binary search probes.  We additionally check that the
 fast and ILP analyses agree on which pairs/cases are feasible at all (an
 II-independent property the fast path must also get right).
 """
+import functools
+import re
+
 import numpy as np
 import pytest
 
+from repro.core import deps, hls, programs, telemetry
 from repro.core.autotune import autotune
 from repro.core.deps import DepAnalysis
 from repro.core.programs import (BENCHMARKS, fig1_conv_chain, fig3_conv1d)
@@ -49,6 +53,105 @@ def test_corpus_fastpath_matches_ilp(name, p):
 @pytest.mark.parametrize("name,p", _corpus(32), ids=lambda v: v if isinstance(v, str) else "")
 def test_corpus_fastpath_matches_ilp_fullsize(name, p):
     _differential(p, require_no_fallback=True)
+
+
+# ---------------------------------------------------------------------------
+# tiled loops: coupled cases solved by branching on the narrowest box
+# ---------------------------------------------------------------------------
+
+# blur_hd's and two_mm_medium's DSE request (bench/configs/)
+_SEARCH = hls.SearchConfig(moves=("fuse", "tile"), unroll_factors=(),
+                           tile_sizes=(2, 4), max_candidates=8, cache=False)
+
+
+@functools.lru_cache(maxsize=None)
+def _explored(name):
+    """The programs the DSE explores, by description; the fused loop's
+    uid suffix (``bxi_f13``) is dropped, as it depends on the process."""
+    p = {"blur_chain": lambda: programs.blur_chain(8, storage="bram"),
+         "two_mm": lambda: programs.two_mm(6, "bram")}[name]()
+    r = hls.compile(p, objectives=(hls.minimize("latency"),
+                                   hls.minimize("bram")), search=_SEARCH)
+    return {re.sub(r"_f\d+", "", c.desc): c.program for c in r.candidates}
+
+
+@pytest.mark.parametrize("name,desc", [
+    ("blur_chain", "tile(bxi:2,byi:2)"),
+    ("blur_chain", "tile(byi:4)"),
+    ("blur_chain", "fuse | tile(bxi:2)"),
+    ("blur_chain", "fuse | tile(bxi:4)"),
+    ("two_mm", "tile(ci:2,pi:2)"),
+])
+def test_tiled_cases_close_by_branching(name, desc):
+    # a split index 2*t + b couples four variables in one address row; the
+    # intra-tile boxes (2 or 4 values) are narrow enough to branch on
+    before = telemetry.counters.get("hls.dep_cases_branched", 0)
+    _differential(_explored(name)[desc], require_no_fallback=True)
+    assert telemetry.counters.get("hls.dep_cases_branched", 0) > before
+
+
+def test_coupled_case_past_the_budget_stays_with_the_ilp(
+        wide_coupled_program):
+    p = wide_coupled_program
+    dep = _differential(p)
+    assert dep.fallback_cases > 0
+    ref = DepAnalysis(p, fastpath=False)
+    iis = autotune(p, dep)
+    assert dep.memory_edges(iis) == ref.memory_edges(iis)
+
+
+def _random_coupled_system(seed):
+    """Box-bounded integer variables under 1-3 equality rows of 3-5
+    variables each: components the two-variable closed form cannot take."""
+    rng = np.random.default_rng(9000 + seed)
+    nv = int(rng.integers(3, 6))
+    vars = {}
+    for v in range(nv):
+        lo = int(rng.integers(-3, 2))
+        vars[v] = (lo, lo + int(rng.integers(0, 5)), int(rng.integers(-4, 5)))
+    point = {v: int(rng.integers(lo, hi + 1))
+             for v, (lo, hi, _) in vars.items()}
+    rows = []
+    for _ in range(int(rng.integers(1, 4))):
+        cols = rng.choice(nv, size=int(rng.integers(3, nv + 1)),
+                          replace=False)
+        coeffs = {int(v): int(rng.choice([-3, -2, -1, 1, 2, 3]))
+                  for v in cols}
+        # feasible through ``point`` on most seeds, infeasible on the rest
+        rhs = sum(a * point[v] for v, a in coeffs.items())
+        rows.append((coeffs, rhs + int(seed % 5 == 0)))
+    return vars, rows
+
+
+@pytest.mark.parametrize("seed", range(40))
+def test_branching_matches_enumeration(seed):
+    import itertools
+
+    vars, rows = _random_coupled_system(seed)
+    best = None
+    keys = sorted(vars)
+    for pt in itertools.product(*(range(lo, hi + 1)
+                                  for lo, hi, _ in (vars[k] for k in keys))):
+        x = dict(zip(keys, pt))
+        if all(sum(a * x[v] for v, a in c.items()) == e for c, e in rows):
+            val = sum(vars[k][2] * x[k] for k in keys)
+            best = val if best is None else min(best, val)
+    got, _ = deps._solve_separable(vars, rows)
+    assert got is not deps._FALLBACK
+    assert got == best
+
+
+def test_branch_budget_leaves_wide_components_to_the_ilp():
+    # four variables coupled by one row, every box wider than the budget
+    wide = deps._BRANCH_CAP
+    vars = {v: (0, wide, 1) for v in range(4)}
+    rows = [({0: wide + 1, 1: 1, 2: -(wide + 1), 3: -1}, 0)]
+    assert deps._solve_separable(vars, rows)[0] is deps._FALLBACK
+    # one narrow box closes it: pin its 2 values, each branch is 3 vars
+    # with another narrow box below the budget left
+    vars[1] = (0, 1, 1)
+    vars[3] = (0, 1, 1)
+    assert deps._solve_separable(vars, rows) == (0, True)
 
 
 # ---------------------------------------------------------------------------

@@ -163,13 +163,23 @@ def test_compile_records_one_root_and_a_candidate_per_measurement(
     names = {s.name for s in rec.spans}
     assert {"hls.compile", "hls.lint", "hls.explore", "hls.candidate",
             "hls.pass", "hls.verify", "hls.deps", "hls.ii_search",
-            "hls.dep_ilp", "hls.schedule", "hls.resources",
-            "hls.static_check"} <= names
+            "hls.schedule", "hls.resources", "hls.static_check"} <= names
     passes = [s for s in rec.spans if s.name == "hls.pass"]
     assert passes and all("name" in s.attrs for s in passes)
     sm = telemetry.summary(rec)
     assert sm["spans"]["hls.compile"]["count"] == 1
     assert sm["counters"]["hls.compiles"] == 1
+    # the closed form takes every case of the tiled candidates by branching
+    assert sm["counters"]["hls.dep_cases_branched"] > 0
+    assert sm["counters"].get("hls.dep_cases_ilp", 0) == \
+        sm["spans"].get("hls.dep_ilp", {}).get("count", 0) == 0
+
+
+def test_each_ilp_case_records_one_dep_ilp_span(wide_coupled_program):
+    with telemetry.recording() as rec:
+        autotune.compile_program(wide_coupled_program)
+    sm = telemetry.summary(rec)
+    assert sm["counters"]["hls.dep_cases_ilp"] > 0
     assert sm["counters"]["hls.dep_cases_ilp"] == \
         sm["spans"]["hls.dep_ilp"]["count"]
 
