@@ -9,10 +9,9 @@ import pytest
 
 from bench import drive, run
 from bench import spec as bspec
+from bench.tests.sizes import small
 
 SPEC = bspec.load_spec()
-SMALL = {"blur_hd": {"rows": 16, "cols": 128},
-         "two_mm_medium": {"NI": 8, "NJ": 9, "NK": 10, "NL": 11}}
 CELLS = [w["name"] for w in SPEC["workloads"]]
 SEED = 2**35 + 11
 
@@ -50,7 +49,7 @@ FAULTS = {"altered": _altered, "unchanged": _unchanged, "half": _half}
 
 def _run(cell_name, override=None, seconds=0.2):
     cell = bspec.cell(SPEC, cell_name)
-    cfg = {**SMALL[cell["config"]], **(override or {})}
+    cfg = {**small(cell["config"]), **(override or {})}
     result, _ = run.run_cell(SPEC, cell, SEED, seconds, False, t0=0.0,
                              cfg_override=cfg, interpret=True)
     return result

@@ -11,6 +11,10 @@ from bench import spec as bspec
 from repro.core import hls, telemetry
 
 READERS = {"ii_probes": "hls.ii_probes", "dep_ilp_cases": "hls.dep_cases_ilp"}
+SPEC = bspec.load_spec()
+RECOMPILED = sorted({w["config"] for w in SPEC["workloads"]
+                     if bspec.load_traffic(w["traffic"])["kind"]
+                     == "recompile"})
 READINGS = run.Readings(calls=1, trace=None, spans={}, ops=0, nbytes=0,
                         peak=None)
 
@@ -38,13 +42,15 @@ def test_reader_reads_nothing_from_a_program_without_counters(metric,
     assert bspec.load_reader(metric)(READINGS) is None
 
 
-def test_every_compile_of_the_recompile_cell_counts_alike(monkeypatch):
-    """Two compiles with different weights make the same probes and ILP
-    cases, so the process-wide mean is each compile's own count.  The
-    counters are live: the II search probes, and the dependence cases are
-    counted, by the closed form or by the ILP fallback, whichever takes
-    them."""
-    cfg, mod = bspec.load_config("blur_hd")
+@pytest.mark.parametrize("config", RECOMPILED)
+def test_every_compile_of_the_recompile_cell_counts_alike(config,
+                                                          monkeypatch):
+    """For each configuration with a recompile cell: two compiles with
+    different weights make the same probes and ILP cases, so the
+    process-wide mean is each compile's own count.  The counters are live:
+    the II search probes, and the dependence cases are counted, by the
+    closed form or by the ILP fallback, whichever takes them."""
+    cfg, mod = bspec.load_config(config)
     dse = cfg["dse"]
     search = hls.SearchConfig(**{k: tuple(v) if isinstance(v, list) else v
                                  for k, v in dse["search"].items()})

@@ -9,7 +9,8 @@ import pytest
 
 from bench import drive, peaks, run
 from bench import spec as bspec
-from bench.tests.test_faults import CELLS, SEED, SMALL, SPEC
+from bench.tests.sizes import small
+from bench.tests.test_faults import CELLS, SEED, SPEC
 from repro.core import telemetry
 
 READERS = {"ii_search_s": "hls.ii_search", "dep_ilp_s": "hls.dep_ilp",
@@ -68,7 +69,7 @@ def test_program_readings_count_root_compiles():
 def _run(cell_name, trace, seconds=0.2):
     cell = bspec.cell(SPEC, cell_name)
     return run.run_cell(SPEC, cell, SEED, seconds, trace, t0=0.0,
-                        cfg_override=SMALL[cell["config"]], interpret=True)
+                        cfg_override=small(cell["config"]), interpret=True)
 
 
 def _kept_readings(monkeypatch):

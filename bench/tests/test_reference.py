@@ -9,15 +9,20 @@ import pytest
 
 from bench import compare, peaks
 from bench import spec as bspec
+from bench.tests.sizes import small
 from repro.core import sim
 
-SMALL = {"blur_hd": {"rows": 16, "cols": 24},
-         "two_mm_medium": {"NI": 6, "NJ": 7, "NK": 8, "NL": 9}}
+CONFIGS = [c["name"] for c in bspec.load_spec()["configs"]]
+# each configuration with its nominal constants, and with drawn ones where
+# it draws them (``draw_weights``), as its recompile steps do
+CASES = ([(name, False) for name in CONFIGS]
+         + [(name, True) for name in CONFIGS
+            if "draw_weights" in bspec.load_config(name)[0]])
 
 
 def _case(name, drawn=False):
     cfg, mod = bspec.load_config(name)
-    cfg = {**cfg, **SMALL[name]}
+    cfg = {**cfg, **small(name)}
     consts = mod.consts(cfg, np.random.default_rng(5) if drawn else None)
     p = mod.program(cfg, consts)
     arrays = sim.make_inputs(p, seed=3)
@@ -27,8 +32,7 @@ def _case(name, drawn=False):
     return cfg, mod, consts, p, arrays
 
 
-@pytest.mark.parametrize("name,drawn", [("blur_hd", False), ("blur_hd", True),
-                                        ("two_mm_medium", False)])
+@pytest.mark.parametrize("name,drawn", CASES)
 def test_reference_matches_sequential_exec(name, drawn):
     cfg, mod, consts, p, arrays = _case(name, drawn)
     want = sim.sequential_exec(p, arrays)
@@ -37,7 +41,7 @@ def test_reference_matches_sequential_exec(name, drawn):
         np.testing.assert_allclose(got[out], want[out], rtol=1e-12, atol=0)
 
 
-@pytest.mark.parametrize("name", sorted(SMALL))
+@pytest.mark.parametrize("name", CONFIGS)
 def test_bfloat16_fails_the_comparison(name):
     cfg, mod, consts, p, arrays = _case(name)
     f32 = {a: v.astype(np.float32) for a, v in arrays.items()}

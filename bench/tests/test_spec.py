@@ -1,6 +1,7 @@
 """BENCHMARK.json resolves to files that exist, by name, and keeps to the
 benchmark's naming rules; the harness refuses to run without a TPU."""
 import json
+import math
 import os
 import subprocess
 import sys
@@ -8,6 +9,7 @@ import sys
 import pytest
 
 from bench import spec as bspec
+from repro.core.ir import Program
 
 SPEC = bspec.load_spec()
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -30,6 +32,26 @@ def test_config_resolves(cfg):
     for fn in ("program", "consts", "reference", "counts"):
         assert callable(getattr(mod, fn))
     assert any(w["config"] == cfg["name"] for w in SPEC["workloads"])
+
+
+@pytest.mark.parametrize("cfg", SPEC["configs"], ids=lambda c: c["name"])
+def test_config_has_a_small_size(cfg):
+    """Every configuration names ``small``, the sizes the CPU tests drive
+    it at (``bench/tests/sizes.py``), and its module builds a program
+    there, smaller than the deployment's."""
+    data, mod = bspec.load_config(cfg["name"])
+    small = data.get("small")
+    assert isinstance(small, dict) and small, (
+        f"{cfg['file']} has no 'small': the sizes its tests run at")
+    assert set(small) <= set(data), (cfg["name"], set(small) - set(data))
+    consts = mod.consts(data)
+    at_small = mod.program({**data, **small}, consts)
+    assert isinstance(at_small, Program), cfg["name"]
+
+    def elements(p):
+        return sum(math.prod(a.shape) for a in p.arrays.values())
+
+    assert elements(at_small) < elements(mod.program(data, consts))
 
 
 @pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda w: w["name"])
