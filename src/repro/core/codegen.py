@@ -480,13 +480,16 @@ def _sel(const: int, coef: int, n: int):
 def _index(v: str, items: list) -> str:
     """Source for ``v[items]``.  A str item indexes as written (one without
     a colon drops its axis); a ``(start, n, step)`` item is sliced whole and
-    decimated by the emitted ``_take``."""
+    decimated by the emitted ``_take``.  Each ``_take`` along axis 1, the
+    lane axis, adds one to the counter ``hls.lane_takes``."""
     expr = f"{v}[" + ", ".join(":" if isinstance(it, tuple) else it
                                for it in items) + "]"
     axis = 0
     for it in items:
         if isinstance(it, tuple):
             expr = f"_take({expr}, {axis}, {it[0]}, {it[1]}, {it[2]})"
+            if axis == 1:
+                telemetry.count("hls.lane_takes")
         if isinstance(it, tuple) or ":" in it:
             axis += 1
     return expr
@@ -615,6 +618,7 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
               "def run(arrays, interpret=False):"]
     args = []
     specs = []
+    pad_elems = 0
     for a in plan.inputs:
         v = f"x_{_ident(a)}"
         lines.append(f"    {v} = jnp.asarray(arrays['{a}'], DTYPE)")
@@ -623,6 +627,7 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
         if pad:
             lines.append(f"    {v} = jnp.pad({v}, ((0, {pad}), (0, 0)), "
                          "mode='edge')")
+            pad_elems += (h + pad) * w
         args.append(v)
         specs.append(f"pl.BlockSpec(({h + pad}, {w}), lambda t: (0, 0))")
     lines.append("    out = pl.pallas_call(")
@@ -641,7 +646,8 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
     meta = {"mode": "streamed", "grid": (T,), "block_rows": B,
             "halo": dict(plan.halo), "outputs": (sink.out,),
             "vmem_window_elems": {s.out: s.win_sz * s.nest.trips[1]
-                                  for s in plan.stages if s is not sink}}
+                                  for s in plan.stages if s is not sink},
+            "edge_pad_elems": pad_elems}
     return "\n".join(lines) + "\n", meta
 
 
@@ -913,7 +919,8 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
     lines.append("    return {" + ", ".join(
         f"'{a}': outs[{i}]" for i, a in enumerate(stored)) + "}")
     meta = {"mode": "whole", "grid": (), "block_rows": None, "halo": {},
-            "outputs": tuple(stored), "vmem_window_elems": {}}
+            "outputs": tuple(stored), "vmem_window_elems": {},
+            "edge_pad_elems": 0}
     return "\n".join(lines) + "\n", meta
 
 
@@ -987,6 +994,10 @@ def lower_program(p: Program, *, block_rows: Optional[int] = None,
         ns: dict = {}
         with telemetry.span("hls.lower.exec"):
             exec(compile(src, f"<codegen:{p.name}>", "exec"), ns)
+        # what the emitted run() edge-pads, every input whole, per call
+        telemetry.count("hls.edge_pad_bytes",
+                        meta["edge_pad_elems"] * ns["DTYPE"].itemsize)
+        telemetry.count("hls.lowers")
     return PallasKernel(program_name=p.name, mode=meta["mode"],
                         buffering=buffering if meta["mode"] == "streamed"
                         else "whole", source=src, fn=ns["run"],

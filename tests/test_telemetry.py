@@ -1,6 +1,8 @@
 """In-program spans and counters (``repro.core.telemetry``): how spans
 nest and reduce, what recording off costs, and that recording changes
 nothing a compile or a lowering produces."""
+import ast
+import hashlib
 import importlib
 import os
 import subprocess
@@ -9,7 +11,7 @@ from pathlib import Path
 
 import pytest
 
-from repro.core import hls, programs, telemetry
+from repro.core import codegen, hls, programs, telemetry
 from repro.core.codegen import lower_program
 
 # the module, not the function ``repro.core.autotune`` it exports
@@ -221,3 +223,48 @@ def test_lowered_source_identical_with_recording_on_and_off():
     kids = [s.name for s in rec.spans if s.parent == lower[0].id]
     assert kids == ["hls.lint", "hls.lower.extract", "hls.lower.plan",
                     "hls.lower.emit", "hls.lower.exec"]
+
+
+# sha256 of each emitted module before the codegen counters existed: the
+# counters are counted beside the emitted text and change none of it
+_SOURCE_SHA256 = {
+    "blur_chain": ("3638ab7a70fb2aa6801591f679562bc2"
+                   "1cd7ea7428d12b988312f872a334a077"),
+    "conv_pool": ("0b79845d0d9f0b5b7b3f0ab64504f75c"
+                  "999c3ad8270488e6ed109b4ee422c81d"),
+}
+
+
+def _lane_takes_in(source: str) -> int:
+    """``_take`` calls along axis 1 in the emitted kernel body."""
+    kernel = next(n for n in ast.parse(source).body
+                  if isinstance(n, ast.FunctionDef) and n.name == "_kernel")
+    return sum(1 for n in ast.walk(kernel)
+               if isinstance(n, ast.Call) and getattr(n.func, "id", "") == "_take"
+               and isinstance(n.args[1], ast.Constant) and n.args[1].value == 1)
+
+
+@pytest.mark.parametrize("name,n", [("blur_chain", 16), ("conv_pool", 24)])
+def test_lowering_counters_are_exact(name, n, monkeypatch):
+    """``hls.lowers`` is one per lowering; ``hls.lane_takes`` the lane-axis
+    takes the emitted kernel holds; ``hls.edge_pad_bytes`` what ``run()``'s
+    edge pad writes per call, from the plan's pad rows.  blur_chain takes
+    nothing and pads nothing; conv_pool's pool reads its four taps at
+    stride 2 and its last row block runs past the input."""
+    p = getattr(programs, name)(n)
+    nests, _ = codegen._extract_nests(p)
+    plan, _ = codegen._plan_streamed(p, nests, 8)
+    pad_bytes = sum((p.arrays[a].shape[0] + r) * p.arrays[a].shape[1] * 4
+                    for a, r in plan.pad_rows.items())
+    monkeypatch.setattr(telemetry, "counters", {})
+    k = lower_program(p, block_rows=8)
+    assert k.mode == "streamed"
+    assert hashlib.sha256(k.source.encode()).hexdigest() == _SOURCE_SHA256[name]
+    want = {"blur_chain": (0, 0), "conv_pool": (4, (26 + 8) * 26 * 4)}[name]
+    assert (_lane_takes_in(k.source), pad_bytes) == want
+    names = ("hls.lowers", "hls.lane_takes", "hls.edge_pad_bytes")
+    assert set(telemetry.counters) <= set(names)
+    assert [telemetry.counters.get(c, 0) for c in names] == [1, *want]
+    lower_program(p, block_rows=8)
+    assert [telemetry.counters.get(c, 0) for c in names] == \
+        [2, 2 * want[0], 2 * want[1]]
