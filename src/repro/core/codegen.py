@@ -55,7 +55,7 @@ the generated blur-chain kernel is bit-exact against the hand-written
 from __future__ import annotations
 
 import re
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Callable, Optional
 
 from . import telemetry
@@ -307,7 +307,7 @@ def _extract_nests(p: Program) -> tuple[list[_Nest], list]:
 @dataclass
 class _StagePlan:
     nest: _Nest
-    out: str                    # produced array
+    out: str                    # produced array, or one phase copy of it
     r0: int                     # store row/col offsets into the array
     c0: int
     win_a: int = 0              # domain rows [a*t+b, a*t+b+sz) per grid step
@@ -324,6 +324,8 @@ class _StreamPlan:
     inputs: list[str]           # arrays read from refs (not stage-produced)
     pad_rows: dict[str, int]    # input array -> trailing edge-pad rows
     halo: dict[str, int]        # produced array -> window overhang (sz - a)
+    # phase copy or input plane -> (array, (row, col) strides, phases)
+    phases: dict[str, tuple]
 
 
 def _plan_streamed(p: Program, nests: list[_Nest],
@@ -402,6 +404,10 @@ def _plan_streamed(p: Program, nests: list[_Nest],
                             f"'{acc.array}' reads outside the producer's "
                             "written box")
                 return None, soft
+    assert stages[-1] is sink  # a later stage would be read, or a sink
+    stages, phases = _split_phases(stages)
+    sink = stages[-1]
+    produced = {s.out: i for i, s in enumerate(stages)}
     # backward window propagation: sink computes [B*t, B*t+B)
     rout = sink.nest.trips[0]
     B = max(1, min(block_rows, rout))
@@ -432,7 +438,9 @@ def _plan_streamed(p: Program, nests: list[_Nest],
                         f"(offset {lo})")
             return None, soft
         s.win_a, s.win_b, s.win_sz = a, lo, hi - lo + 1
-        halo[s.out] = s.win_sz - s.win_a
+        arr = phases.get(s.out, (s.out,))[0]
+        halo[arr] = max(halo.get(arr, s.win_sz - s.win_a),
+                        s.win_sz - s.win_a)
     T = -(-rout // B)
     # trailing edge-padding so the last (possibly partial) tile's input
     # reads stay in bounds; padded rows only feed output rows >= Rout,
@@ -447,11 +455,82 @@ def _plan_streamed(p: Program, nests: list[_Nest],
                 inputs.append(acc.array)
             rc, rk = acc.dims[0][1], acc.dims[0][2]
             need = rc * (s.win_a * (T - 1) + s.win_b + s.win_sz - 1) + rk
-            over = need - (p.arrays[acc.array].shape[0] - 1)
+            # a plane's row i is its array's row stride * i + phase
+            arr, (st, _), (u, _) = phases.get(acc.array,
+                                              (acc.array, (1, 1), (0, 0)))
+            over = st * need + u - (p.arrays[arr].shape[0] - 1)
             if over > 0:
-                pad_rows[acc.array] = max(pad_rows.get(acc.array, 0), over)
+                pad_rows[arr] = max(pad_rows.get(arr, 0), over)
     return _StreamPlan(stages=stages, sink=sink, block_rows=B, grid=T,
-                       inputs=inputs, pad_rows=pad_rows, halo=halo), soft
+                       inputs=inputs, pad_rows=pad_rows, halo=halo,
+                       phases=phases), soft
+
+
+def _phase_copy(s: _StagePlan, out: str, stride: tuple,
+                phase: tuple) -> _StagePlan:
+    """Stage ``s`` evaluated where its array's (row, col) index is
+    ``stride * i + phase``, as a stage writing ``out[i]``: the stride is
+    folded into the coefficients and offsets of its own reads."""
+    n = s.nest
+    off = (s.r0, s.c0)
+    # the first and last i whose domain index lies in the stage's domain
+    lo = [-((u - o) // st) for u, o, st in zip(phase, off, stride)]
+    hi = [(o + trip - 1 - u) // st
+          for u, o, st, trip in zip(phase, off, stride, n.trips)]
+    base = [st * i + u - o for i, u, o, st in zip(lo, phase, off, stride)]
+    loads = [(op, _Access(acc.array, [(iv, k * st, k * b + c) for
+                                      (iv, k, c), st, b in
+                                      zip(acc.dims, stride, base)]))
+             for op, acc in n.loads]
+    nest = replace(n, trips=[h - i + 1 for i, h in zip(lo, hi)], loads=loads)
+    return _StagePlan(nest=nest, out=out, r0=lo[0], c0=lo[1])
+
+
+def _split_phases(stages: list[_StagePlan]) -> tuple[list[_StagePlan], dict]:
+    """Polyphase lowering of strided reads.  Where every read of an array
+    along an axis has one coefficient ``s > 1``, once pushed through the
+    stages that read it, the array is split into its phases along that
+    axis and each read becomes a unit-stride read of one phase: a produced
+    array gets one copy of its stage per phase read (``_phase_copy``), an
+    input one plane per phase read, which ``run()`` slices.  Where strides
+    mix, the axis stays whole and its reads strided (``_take``).  Returns
+    the stages, each replaced by its copies in phase order, and the phase
+    map: copy or plane -> (array, (row, col) strides, phases)."""
+    pending: dict[str, list[_Access]] = {}  # array -> reads not yet split
+    phases: dict[str, tuple] = {}
+
+    def split(arr: str, accs: list[_Access]) -> tuple[tuple, dict]:
+        stride = []
+        for d in range(2):
+            ks = {a.dims[d][1] for a in accs if a.dims[d][0] is not None}
+            stride.append(ks.pop() if len(ks) == 1 else 1)
+        stride = tuple(stride)
+        names: dict[tuple, str] = {}
+        for a in accs:
+            u = tuple(c % st for (_, _, c), st in zip(a.dims, stride))
+            a.dims = [(iv, k // st, c // st)
+                      for (iv, k, c), st in zip(a.dims, stride)]
+            if u not in names:
+                names[u] = (arr if stride == (1, 1)
+                            else f"{arr}@{u[0]}_{u[1]}")
+                if stride != (1, 1):
+                    phases[names[u]] = (arr, stride, u)
+            a.array = names[u]
+        return stride, names
+
+    copies: list[list[_StagePlan]] = []
+    for s in reversed(stages):
+        # the unique sink is the last stage, and nothing reads it
+        stride, names = (split(s.out, pending.pop(s.out)) if copies
+                         else ((1, 1), {(0, 0): s.out}))
+        cs = [_phase_copy(s, names[u], stride, u) for u in sorted(names)]
+        for c in cs:
+            for _, acc in c.nest.loads:
+                pending.setdefault(acc.array, []).append(acc)
+        copies.append(cs)
+    for arr, accs in pending.items():  # the inputs
+        split(arr, accs)
+    return [c for cs in reversed(copies) for c in cs], phases
 
 
 # ---------------------------------------------------------------------------
@@ -480,8 +559,10 @@ def _sel(const: int, coef: int, n: int):
 def _index(v: str, items: list) -> str:
     """Source for ``v[items]``.  A str item indexes as written (one without
     a colon drops its axis); a ``(start, n, step)`` item is sliced whole and
-    decimated by the emitted ``_take``.  Each ``_take`` along axis 1, the
-    lane axis, adds one to the counter ``hls.lane_takes``."""
+    decimated by the emitted ``_take``.  Mode B's strided reads come here,
+    and Mode A's only on an axis whose strides mix (``_split_phases`` makes
+    the others unit-stride reads of phases).  Each ``_take`` along axis 1,
+    the lane axis, adds one to the counter ``hls.lane_takes``."""
     expr = f"{v}[" + ", ".join(":" if isinstance(it, tuple) else it
                                for it in items) + "]"
     axis = 0
@@ -499,7 +580,10 @@ def _index(v: str, items: list) -> str:
 # neither strided vector slices nor, for a last dim other than 128, strided
 # loads), so the generated module takes a run of ``n * step`` rows, splits
 # it into (n, step) with a reshape and keeps one phase; columns go through a
-# transpose.  Both are exact data movement.
+# transpose.  Both are exact data movement, and the column take moves every
+# vreg through a transpose twice.  Mode A reaches it only where an axis's
+# strides mix; elsewhere its strided reads are phase planes that run()
+# slices with XLA before the kernel (``_split_phases``).
 _TAKE_HELPER = '''
 
 def _take(x, axis, start, n, step):
@@ -619,7 +703,10 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
     args = []
     specs = []
     pad_elems = 0
-    for a in plan.inputs:
+    # each ref: (array, strides, phases), the whole array where unsplit
+    refs_of = {a: plan.phases.get(a, (a, (1, 1), (0, 0)))
+               for a in plan.inputs}
+    for a in dict.fromkeys(src for src, _, _ in refs_of.values()):
         v = f"x_{_ident(a)}"
         lines.append(f"    {v} = jnp.asarray(arrays['{a}'], DTYPE)")
         pad = plan.pad_rows.get(a, 0)
@@ -628,8 +715,28 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
             lines.append(f"    {v} = jnp.pad({v}, ((0, {pad}), (0, 0)), "
                          "mode='edge')")
             pad_elems += (h + pad) * w
+        # Phase planes are sliced after the edge pad, columns first, as
+        # rows of the transpose: on the chip a step along rows runs at
+        # copy speed, one along the lane axis element by element (a
+        # stepped jnp index is a gather; a strided lax.slice is faster,
+        # but still ten times slower than the transposes).
+        cols = sorted({(st[1], u[1]) for src, st, u in refs_of.values()
+                       if src == a and st[1] > 1})
+        if cols:
+            lines.append(f"    t_{_ident(a)} = {v}.T")
+        for sc, q in cols:
+            lines.append(f"    c{q}_{_ident(a)} = t_{_ident(a)}[{q}::{sc}].T")
+    for a, (src, stride, phase) in refs_of.items():
+        v = f"x_{_ident(a)}"
+        if a in plan.phases:
+            (sr, sc), (pr, pc) = stride, phase
+            x = f"c{pc}_{_ident(src)}" if sc > 1 else f"x_{_ident(src)}"
+            lines.append(f"    {v} = {x}" + (f"[{pr}::{sr}]" if sr > 1 else ""))
+        rows = p.arrays[src].shape[0] + plan.pad_rows.get(src, 0)
+        h, w = (-(-(n - u) // st) for n, st, u in
+                zip((rows, p.arrays[src].shape[1]), stride, phase))
         args.append(v)
-        specs.append(f"pl.BlockSpec(({h + pad}, {w}), lambda t: (0, 0))")
+        specs.append(f"pl.BlockSpec(({h}, {w}), lambda t: (0, 0))")
     lines.append("    out = pl.pallas_call(")
     lines.append("        _kernel,")
     if buffering == "double":
@@ -643,11 +750,14 @@ def _emit_streamed(p: Program, plan: _StreamPlan, buffering: str,
     lines.append("    )(" + ", ".join(args) + ")")
     trim = f"[:{rout}]" if T * B != rout else ""
     lines.append(f"    return {{'{sink.out}': out{trim}}}")
+    window: dict[str, int] = {}  # produced array -> elements, all copies
+    for s in plan.stages[:-1]:
+        arr = plan.phases.get(s.out, (s.out,))[0]
+        window[arr] = window.get(arr, 0) + s.win_sz * s.nest.trips[1]
     meta = {"mode": "streamed", "grid": (T,), "block_rows": B,
             "halo": dict(plan.halo), "outputs": (sink.out,),
-            "vmem_window_elems": {s.out: s.win_sz * s.nest.trips[1]
-                                  for s in plan.stages if s is not sink},
-            "edge_pad_elems": pad_elems}
+            "vmem_window_elems": window, "edge_pad_elems": pad_elems,
+            "phase_planes": sum(a in plan.phases for a in plan.inputs)}
     return "\n".join(lines) + "\n", meta
 
 
@@ -920,7 +1030,7 @@ def _emit_whole(p: Program, nests: list[_Nest], dtype: str) -> tuple[str, dict]:
         f"'{a}': outs[{i}]" for i, a in enumerate(stored)) + "}")
     meta = {"mode": "whole", "grid": (), "block_rows": None, "halo": {},
             "outputs": tuple(stored), "vmem_window_elems": {},
-            "edge_pad_elems": 0}
+            "edge_pad_elems": 0, "phase_planes": 0}
     return "\n".join(lines) + "\n", meta
 
 
@@ -994,9 +1104,11 @@ def lower_program(p: Program, *, block_rows: Optional[int] = None,
         ns: dict = {}
         with telemetry.span("hls.lower.exec"):
             exec(compile(src, f"<codegen:{p.name}>", "exec"), ns)
-        # what the emitted run() edge-pads, every input whole, per call
+        # what the emitted run() edge-pads, every input whole, per call,
+        # and the phase planes it then slices
         telemetry.count("hls.edge_pad_bytes",
                         meta["edge_pad_elems"] * ns["DTYPE"].itemsize)
+        telemetry.count("hls.phase_planes", meta["phase_planes"])
         telemetry.count("hls.lowers")
     return PallasKernel(program_name=p.name, mode=meta["mode"],
                         buffering=buffering if meta["mode"] == "streamed"

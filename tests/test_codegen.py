@@ -320,3 +320,111 @@ def test_strided_helpers_match_numpy(axis, step):
             assert np.array_equal(got, want), (start, n)
     got = np.asarray(ns["_spread"](jax.numpy.asarray(x), axis, step))
     assert np.array_equal(got, np.repeat(x, step, axis=axis))
+
+
+# ---------------------------------------------------------------------------
+# polyphase lowering of strided reads (Mode A)
+# ---------------------------------------------------------------------------
+
+
+def _strided_program(name: str, n: int):
+    """Mode A programs whose reads carry strides.
+
+    ``decimate``: one stage reading an input at stride 2 on both axes, two
+    of its four phases.  ``blur_pool``: a 3 x 1 blur into an interior box
+    of ``bx`` (a store offset), a 1 x 3 blur, then a 2 x 2 stride-2 max,
+    so the stride passes through two unit-stride stages to the input.
+    ``mixed``: an input read at strides 2 and 1 on both axes.
+    ``mixed_cols``: an input read at stride 2 on every row and at strides 2
+    and 1 on the columns."""
+    b = ProgramBuilder(name)
+    port = dict(partition=(0,), ports=("w", "r", "r", "r"))
+    if name == "blur_pool":
+        b.array("img", (2 * n + 2, 2 * n + 2), is_arg=True, **port)
+        b.array("bx", (2 * n + 1, 2 * n + 2), **port)
+        b.array("by", (2 * n, 2 * n), **port)
+        b.array("out", (n, n), is_arg=True, **port)
+        with b.loop("xi", 0, 2 * n) as i:
+            with b.loop("xj", 0, 2 * n + 2) as j:
+                b.store("bx", b.sum_tree([
+                    b.mul(b.load("img", i + u, j), b.const(0.25 + 0.25 * u))
+                    for u in range(3)]), i + 1, j)
+        with b.loop("yi", 0, 2 * n) as i:
+            with b.loop("yj", 0, 2 * n) as j:
+                b.store("by", b.sum_tree([
+                    b.mul(b.load("bx", i + 1, j + v), b.const(0.5 - 0.125 * v))
+                    for v in range(3)]), i, j)
+        with b.loop("pi", 0, n) as i:
+            with b.loop("pj", 0, n) as j:
+                m = None
+                for u in range(2):
+                    for v in range(2):
+                        x = b.load("by", 2 * i + u, 2 * j + v)
+                        m = x if m is None else b.arith("max", m, x)
+                b.store("out", m, i, j)
+        return b.build()
+    b.array("img", (2 * n + 2, 2 * n + 2), is_arg=True, **port)
+    b.array("out", (n, n), is_arg=True, **port)
+    with b.loop("i", 0, n) as i:
+        with b.loop("j", 0, n) as j:
+            x = b.load("img", 2 * i, 2 * j)
+            if name == "decimate":
+                y = b.load("img", 2 * i + 1, 2 * j + 3)
+            elif name == "mixed":
+                y = b.load("img", i + 1, j)
+            else:
+                y = b.load("img", 2 * i + 1, j + 1)
+            b.store("out", b.sub(b.mul(x, b.const(0.75)), y), i, j)
+    return b.build()
+
+
+@pytest.mark.parametrize("n,block_rows", [(8, 8), (12, 4), (20, 8), (26, 8)])
+def test_conv_pool_lowers_to_phase_planes(n, block_rows, monkeypatch):
+    """conv_pool's pool reads the blur at stride 2 on both axes: the blur
+    is emitted once per phase and the input split into four planes, so the
+    kernel holds no ``_take``.  Exact against the float64 oracle and
+    bit-identical between double and single buffering, at sizes whose last
+    row block is whole, partial, or the only one."""
+    from repro.core import programs, telemetry
+
+    p = programs.conv_pool(n, storage="bram")
+    monkeypatch.setattr(telemetry, "counters", {})
+    k = lower_program(p, block_rows=block_rows, dtype="float64")
+    assert k.mode == "streamed"
+    assert "_take" not in k.source
+    assert [telemetry.counters.get(c, 0) for c in
+            ("hls.lowers", "hls.phase_planes", "hls.lane_takes")] == [1, 4, 0]
+    assert k.halo == {"conv": 0}
+    _exact(k, p)
+    inputs = sim.make_inputs(p, seed=3)
+    kd, ks = (lower_program(p, block_rows=block_rows, buffering=b)
+              for b in ("double", "single"))
+    assert np.array_equal(np.asarray(kd(inputs, interpret=True)["pool"]),
+                          np.asarray(ks(inputs, interpret=True)["pool"]))
+
+
+@pytest.mark.parametrize("name,planes,takes", [
+    ("decimate", ["img@0_0", "img@1_1"], 0),
+    ("blur_pool", ["img@0_0", "img@0_1", "img@1_0", "img@1_1"], 0),
+    ("mixed", [], 1),
+    ("mixed_cols", ["img@0_0", "img@1_0"], 1),
+])
+@pytest.mark.parametrize("buffering", ["double", "single"])
+def test_strided_reads_split_into_phases_where_strides_agree(
+        name, planes, takes, buffering, monkeypatch):
+    """An axis whose reads of an array all carry one stride is split into
+    phase planes, the planes read being the phases the program touches; an
+    axis whose strides mix keeps the array whole and decimates with
+    ``_take`` (the lane-axis ones counted).  Every variant is exact."""
+    from repro.core import codegen, telemetry
+
+    p = _strided_program(name, 7)
+    nests, _ = codegen._extract_nests(p)
+    plan, _ = codegen._plan_streamed(p, nests, 4)
+    assert sorted(plan.inputs) == (planes or ["img"])
+    monkeypatch.setattr(telemetry, "counters", {})
+    k = lower_program(p, block_rows=4, buffering=buffering, dtype="float64")
+    assert k.mode == "streamed" and k.grid == (2,)
+    assert telemetry.counters.get("hls.phase_planes", 0) == len(planes)
+    assert telemetry.counters.get("hls.lane_takes", 0) == takes
+    _exact(k, p)

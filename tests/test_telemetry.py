@@ -3,6 +3,7 @@ nest and reduce, what recording off costs, and that recording changes
 nothing a compile or a lowering produces."""
 import ast
 import hashlib
+import re
 import importlib
 import os
 import subprocess
@@ -225,13 +226,15 @@ def test_lowered_source_identical_with_recording_on_and_off():
                     "hls.lower.emit", "hls.lower.exec"]
 
 
-# sha256 of each emitted module before the codegen counters existed: the
-# counters are counted beside the emitted text and change none of it
+# sha256 of each emitted module: blur_chain's from before the codegen
+# counters existed (the counters are counted beside the emitted text and
+# change none of it, and a plan with no strided read emits what it did
+# before phase planes), conv_pool's since its strided reads became planes
 _SOURCE_SHA256 = {
     "blur_chain": ("3638ab7a70fb2aa6801591f679562bc2"
                    "1cd7ea7428d12b988312f872a334a077"),
-    "conv_pool": ("0b79845d0d9f0b5b7b3f0ab64504f75c"
-                  "999c3ad8270488e6ed109b4ee422c81d"),
+    "conv_pool": ("46a951ee15d9e707649513ba0b428105"
+                  "be8b0b8d2d7cbb14b7fe0f1c6f54a7ae"),
 }
 
 
@@ -248,9 +251,11 @@ def _lane_takes_in(source: str) -> int:
 def test_lowering_counters_are_exact(name, n, monkeypatch):
     """``hls.lowers`` is one per lowering; ``hls.lane_takes`` the lane-axis
     takes the emitted kernel holds; ``hls.edge_pad_bytes`` what ``run()``'s
-    edge pad writes per call, from the plan's pad rows.  blur_chain takes
-    nothing and pads nothing; conv_pool's pool reads its four taps at
-    stride 2 and its last row block runs past the input."""
+    edge pad writes per call, from the plan's pad rows;
+    ``hls.phase_planes`` the input planes ``run()`` slices.  blur_chain
+    takes, pads and splits nothing; conv_pool's pool reads its four taps at
+    stride 2, which split the input into four planes and leave no take,
+    and its last row block runs past the input."""
     p = getattr(programs, name)(n)
     nests, _ = codegen._extract_nests(p)
     plan, _ = codegen._plan_streamed(p, nests, 8)
@@ -260,11 +265,14 @@ def test_lowering_counters_are_exact(name, n, monkeypatch):
     k = lower_program(p, block_rows=8)
     assert k.mode == "streamed"
     assert hashlib.sha256(k.source.encode()).hexdigest() == _SOURCE_SHA256[name]
-    want = {"blur_chain": (0, 0), "conv_pool": (4, (26 + 8) * 26 * 4)}[name]
-    assert (_lane_takes_in(k.source), pad_bytes) == want
-    names = ("hls.lowers", "hls.lane_takes", "hls.edge_pad_bytes")
+    want = {"blur_chain": (0, 0, 0),
+            "conv_pool": (0, (26 + 8) * 26 * 4, 4)}[name]
+    assert (_lane_takes_in(k.source), pad_bytes,
+            len(re.findall(r"^    x_img_\d+_\d+ = ", k.source, re.M))) == want
+    names = ("hls.lowers", "hls.lane_takes", "hls.edge_pad_bytes",
+             "hls.phase_planes")
     assert set(telemetry.counters) <= set(names)
     assert [telemetry.counters.get(c, 0) for c in names] == [1, *want]
     lower_program(p, block_rows=8)
     assert [telemetry.counters.get(c, 0) for c in names] == \
-        [2, 2 * want[0], 2 * want[1]]
+        [2, *(2 * w for w in want)]
