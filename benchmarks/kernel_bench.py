@@ -47,8 +47,6 @@ def run(emit):
     br, halo = _stencil_codegen_config()
     rows.append(("kernel.stencil_pipeline.dse_config", 0.0,
                  f"block_rows={br};halo={halo}"))
-    rows.append(("kernel.stencil_pipeline.ilp_halo_rows_fallback", 0.0,
-                 ops.ilp_halo_rows(3)))
     # wkv6
     B, H, S, hd = 1, 2, 128, 64
     ks = jax.random.split(jax.random.key(2), 4)

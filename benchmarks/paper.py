@@ -18,7 +18,11 @@ DSE_JSON = os.path.join(os.path.dirname(__file__), os.pardir, "BENCH_dse.json")
 FUSION_JSON = os.path.join(os.path.dirname(__file__), os.pardir,
                            "BENCH_fusion.json")
 
-# Reduced benchmark sizes for the DSE sweep (explore() compiles ~a dozen
+# The iso-resource budget of the DSE and fusion sweeps: the untransformed
+# design's own BRAM and DSP are the ceilings.
+ISO_RESOURCE = ("bram <= 1.0x baseline", "dsp <= 1.0x baseline")
+
+# Reduced benchmark sizes for the DSE sweep (the DSE compiles ~a dozen
 # candidates per program and validates the winner with the brute-force
 # oracles, so full-size optical flow would take minutes on this container).
 _DSE_SIZES = {"unsharp": 16, "harris": 8, "dus": 16, "optical_flow": 8,
@@ -113,7 +117,7 @@ def compute_dse(storage: str = "bram", force: bool = False) -> dict:
     if storage in cache and not force:
         return cache[storage]
 
-    from repro.core.api import explore
+    from repro.core import hls
     from repro.core.programs import BENCHMARKS
 
     out = {}
@@ -121,22 +125,25 @@ def compute_dse(storage: str = "bram", force: bool = False) -> dict:
         n = _DSE_SIZES.get(name, 8)
         p = mk(n, storage=storage)
         t0 = time.time()
-        r = explore(p, verify=True, validate=True, max_candidates=16)
+        r = hls.compile(p, constraints=ISO_RESOURCE,
+                        search=hls.SearchConfig(max_candidates=16,
+                                                validate=True))
         out[name] = {
             "n": n,
             "baseline_latency": r.baseline.latency,
             "best_latency": r.best.latency,
             "best_pipeline": r.best.desc,
             "speedup": round(r.speedup, 3),
-            "budget": r.budget,
+            "budget": r.caps,
             "baseline_resources": r.baseline.res,
             "best_resources": r.best.res,
-            "verified": True,   # explore(verify=True, validate=True) raised on
-                                # any differential / validate_schedule failure
+            "verified": True,   # verify + validate raised on any
+                                # differential / validate_schedule failure
             "candidates": [
-                {"pipeline": d, "latency": lat, "bram_bytes": bram,
-                 "dsp": dsp, "within_budget": ok}
-                for d, lat, bram, dsp, ok in r.table()],
+                {"pipeline": c.desc, "latency": c.latency,
+                 "bram_bytes": c.res["bram_bytes"], "dsp": c.res["dsp"],
+                 "within_budget": c.within_budget}
+                for c in sorted(r.candidates, key=_candidate_order)],
             "dse_seconds": round(time.time() - t0, 2),
         }
     cache[storage] = out
@@ -144,10 +151,15 @@ def compute_dse(storage: str = "bram", force: bool = False) -> dict:
     return out
 
 
+def _candidate_order(c) -> tuple:
+    """Within-budget candidates first, then by latency, BRAM and DSP."""
+    return (not c.within_budget, c.latency, c.res["bram_bytes"], c.res["dsp"])
+
+
 def compute_fusion(storage: str = "bram", force: bool = False) -> dict:
     """Shift-and-peel fusion sweep over the mismatched-bounds stencil chains
     (``programs.CHAIN_BENCHMARKS``): for every chain, compare the unfused
-    ``compile_program`` schedule against the best explore() candidate whose
+    ``compile_program`` schedule against the best DSE candidate whose
     pipeline actually fused the chain (nonzero shift / peels recorded in the
     program's ``_fusion_log``).  Results go to ``BENCH_fusion.json``."""
     cache = {}
@@ -156,7 +168,7 @@ def compute_fusion(storage: str = "bram", force: bool = False) -> dict:
     if storage in cache and not force:
         return cache[storage]
 
-    from repro.core.api import explore
+    from repro.core import hls
     from repro.core.programs import CHAIN_BENCHMARKS
 
     out = {}
@@ -164,8 +176,10 @@ def compute_fusion(storage: str = "bram", force: bool = False) -> dict:
         n = _FUSION_SIZES.get(name, 8)
         p = mk(n, storage=storage)
         t0 = time.time()
-        r = explore(p, verify=True, validate=True, max_candidates=10,
-                    unroll_factors=(), tile_sizes=(4,))
+        r = hls.compile(p, constraints=ISO_RESOURCE,
+                        search=hls.SearchConfig(
+                            max_candidates=10, unroll_factors=(),
+                            tile_sizes=(4,), validate=True))
         fused = [c for c in r.candidates
                  if getattr(c.program, "_fusion_log", [])]
         if not fused:
@@ -187,11 +201,11 @@ def compute_fusion(storage: str = "bram", force: bool = False) -> dict:
             "peels": sum(e["peels"] for e in log),
             "speedup": round(r.baseline.latency / best_fused.latency, 3),
             "within_budget": best_fused.within_budget,
-            "budget": r.budget,
+            "budget": r.caps,
             "fused_resources": best_fused.res,
             "baseline_resources": r.baseline.res,
-            "verified": True,   # explore(verify=True, validate=True) raised
-                                # on any differential/validator failure
+            "verified": True,   # verify + validate raised on any
+                                # differential/validator failure
             "fusion_seconds": round(time.time() - t0, 2),
         }
     cache[storage] = out

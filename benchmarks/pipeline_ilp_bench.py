@@ -18,7 +18,7 @@ _BENCH_JSON = pathlib.Path(__file__).resolve().parent.parent / \
 def _compile_corpus_rows():
     """Compile-time rows for the paper benchmark corpus (reduced size so the
     bench run stays interactive; the shape of the trend is what matters)."""
-    from repro.core import compile_program
+    from repro.core.autotune import compile_program
     from repro.core.programs import fig3_conv1d, unsharp, dus, two_mm
 
     rows = []

@@ -1,5 +1,5 @@
-"""Benchmark harness — one section per paper table/figure + the framework's
-own dry-run/roofline tables.  Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness — one section per paper table/figure, plus the
+compiler's own suites.  Prints ``name,us_per_call,derived`` CSV.
 
 ``python benchmarks/run.py`` runs everything; ``python benchmarks/run.py
 SUITE`` runs one suite.  Unknown suite names are a hard argparse error (the
@@ -11,8 +11,7 @@ import argparse
 
 #: every runnable suite — argparse rejects anything else
 SUITES = ("paper", "reg", "bram", "dse", "pareto", "dse-perf", "faults",
-          "fusion", "codegen", "trace", "analysis", "pipeline", "kernels",
-          "roofline")
+          "fusion", "codegen", "trace", "analysis", "pipeline", "kernels")
 
 
 def _emit(rows):
@@ -143,10 +142,6 @@ def main(argv=None) -> None:
     if only in (None, "kernels"):
         from benchmarks import kernel_bench
         kernel_bench.run(_emit)
-
-    if only in (None, "roofline"):
-        from benchmarks import roofline
-        roofline.report(_emit)
 
 
 if __name__ == "__main__":

@@ -3,13 +3,8 @@
 # applications inside the JAX framework (pipeline-parallel schedule synthesis,
 # collective/compute overlap, Pallas line-buffer sizing).
 #
-# The blessed compilation entry point is the declarative front end
-# ``repro.core.hls`` (api.py): ``hls.compile(program, spec)``.  The old
-# ``compile_program``/``explore`` names remain importable from this package
-# but are deprecated shims — accessing them emits one DeprecationWarning
-# (see DESIGN.md §6 MIGRATION).
-import warnings as _warnings
-
+# The compilation entry point is the declarative front end
+# ``repro.core.hls`` (api.py): ``hls.compile(program, spec)``.
 from .ir import (AffExpr, ArrayDecl, ArithOp, ConstOp, LoadOp, Loop, Program,
                  ProgramBuilder, StoreOp, aff, iv, normalize)
 from .ilp import solve_ilp, solve_lp, brute_force_ilp
@@ -61,29 +56,12 @@ __all__ = [
     "PallasKernel", "lower_program",
     # tracing frontend, served lazily (importing it pulls in jax):
     "trace", "TracedProgram",
-    # deprecated shims, served lazily with a DeprecationWarning:
-    "compile_program", "explore",
 ]
-
-_DEPRECATED = {
-    "compile_program": "hls.compile(p, pipeline=()).best.schedule",
-    "explore": 'hls.compile(p, constraints=("bram <= 1.0x baseline", '
-               '"dsp <= 1.0x baseline"))',
-}
 
 
 def __getattr__(name: str):
-    """PEP 562 lazy attributes: the deprecated entry points keep working
-    (``from repro.core import compile_program, explore``) but warn once per
-    import site; internal code imports the primitives from their modules
-    directly and never pays the warning."""
-    if name in _DEPRECATED:
-        _warnings.warn(
-            f"repro.core.{name} is deprecated; use repro.core."
-            f"{_DEPRECATED[name]} instead (DESIGN.md §6 MIGRATION)",
-            DeprecationWarning, stacklevel=2)
-        from . import api
-        return getattr(api, name)
+    """PEP 562 lazy attributes: the tracing frontend is imported on first
+    access only."""
     if name in ("trace", "TracedProgram"):
         # lazy: the frontend imports jax, which the scheduler-only paths
         # never need to pay for

@@ -24,15 +24,6 @@ design like ``bram <= 1.0x baseline``), and optionally a fixed
 ``pipeline_parse``).  With a pipeline the front end compiles exactly that
 program; without one it runs the Pareto-frontier DSE
 (``autotune.pareto_explore``) over the move families in ``SearchConfig``.
-
-The old entry points remain importable from ``repro.core`` as deprecated
-shims (one ``DeprecationWarning`` at access):
-
-    compile_program(p)    ==  hls.compile(p, pipeline=()).best.schedule
-    explore(p, budget)    ==  hls.compile(p, constraints=<budget>) viewed
-                              through the legacy DSEResult shape
-
-(DESIGN.md §6 MIGRATION has the full mapping.)
 """
 from __future__ import annotations
 
@@ -42,7 +33,7 @@ from dataclasses import dataclass, field, replace as dc_replace
 from typing import Optional, Sequence, Union
 
 from . import faults, telemetry
-from .autotune import (DSECandidate, DSEResult, MOVE_FAMILIES,
+from .autotune import (DSECandidate, MOVE_FAMILIES,
                        PARETO_METRICS, ParetoResult, _degrading,
                        dedupe_diagnostics, measure_candidate,
                        pareto_explore, validate_candidate)
@@ -475,8 +466,8 @@ def compile(program: Program, spec: Optional[CompileSpec] = None, *,
       point (plus the baseline when distinct).
     * Without: run the Pareto-frontier DSE and return the full frontier.
 
-    The empty pipeline ``()`` compiles the program as-is — the
-    ``compile_program`` migration path.
+    The empty pipeline ``()`` compiles the program as-is: its schedule is
+    ``autotune.compile_program``'s.
     """
     spec = _resolve_spec(spec, dict(target=target, objectives=objectives,
                                     constraints=constraints,
@@ -588,66 +579,3 @@ def _compile(program: Program, spec: CompileSpec,
                          diagnostics=diagnostics,
                          provenance="degraded" if degraded else "exact")
 
-
-# ---------------------------------------------------------------------------
-# Deprecated shims (surfaced via repro.core.__getattr__ with a
-# DeprecationWarning; see DESIGN.md §6 MIGRATION)
-# ---------------------------------------------------------------------------
-
-
-def compile_program(p: Program, verbose: bool = False):
-    """Deprecated: ``hls.compile(p, pipeline=()).best.schedule``."""
-    if verbose:  # the legacy verbose flag printed autotuner II probes
-        from .autotune import compile_program as _impl
-        return _impl(p, verbose=True)
-    return compile(p, pipeline=()).best.schedule
-
-
-def explore(p: Program, budget: Optional[dict[str, float]] = None, *,
-            unroll_factors: Sequence[int] = (2, 4),
-            tile_sizes: Sequence[int] = (4,),
-            max_candidates: int = 24,
-            verify: bool = True,
-            validate: bool = False,
-            seeds: Sequence[int] = (0,),
-            verbose: bool = False) -> DSEResult:
-    """Deprecated: resource-aware DSE in the legacy ``DSEResult`` shape.
-
-    ``budget`` maps resource names to absolute ceilings (unknown keys
-    raise); ``budget=None`` is iso-resource (baseline BRAM/DSP).  Now
-    backed by the Pareto engine: ``best`` is the budget-feasible
-    minimum-latency frontier point; when the budget rejects EVERY
-    candidate the baseline is returned as ``best`` (``within_budget``
-    False) with the rejection reasons in ``DSEResult.rejections`` /
-    ``explain()``.  Equivalent declarative call::
-
-        hls.compile(p, constraints=("bram <= 1.0x baseline",
-                                    "dsp <= 1.0x baseline"))
-    """
-    from .dataflow import RESOURCE_KEYS
-
-    if budget is not None:
-        unknown = set(budget) - set(RESOURCE_KEYS)
-        if unknown:
-            raise ValueError(
-                f"unknown budget resource(s) {sorted(unknown)}; "
-                f"valid keys: {sorted(RESOURCE_KEYS)}")
-        caps, rel = dict(budget), {}
-    else:
-        caps, rel = {}, {"bram_bytes": 1.0, "dsp": 1.0}
-
-    r = pareto_explore(p, caps=caps, rel_caps=rel,
-                       unroll_factors=unroll_factors, tile_sizes=tile_sizes,
-                       max_candidates=max_candidates, verify=verify,
-                       seeds=seeds, verbose=verbose)
-    feasible = [c for c in r.candidates if c.within_budget]
-    if feasible:
-        best = min(feasible, key=lambda c: (c.latency, c.res["bram_bytes"],
-                                            c.res["dsp"], c.res["ff_bits"]))
-    else:
-        best = r.baseline  # graceful: every candidate rejected
-    if validate:
-        validate_candidate(best, seeds)
-    return DSEResult(baseline=r.baseline, best=best, candidates=r.candidates,
-                     budget=r.caps, frontier=r.frontier,
-                     rejections=r.rejected)

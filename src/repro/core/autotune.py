@@ -19,8 +19,7 @@ every candidate through the incremental scheduler, and maintains a
 dominance-pruned archive over the objective space (latency, BRAM, DSP, FF)
 — the Fig. 9 trade-off curve — expanded frontier-first rather than by
 single-best hill climbing.  The declarative entry point is
-``repro.core.hls.compile`` (api.py); ``explore``/``compile_program`` live
-on as deprecated shims there.
+``repro.core.hls.compile`` (api.py).
 """
 from __future__ import annotations
 
@@ -191,9 +190,8 @@ def dominates(u: Sequence[float], v: Sequence[float],
 
 @dataclass
 class DSEResult:
-    """Legacy result shape of the deprecated ``explore`` shim (the
-    declarative path returns ``hls.CompileResult``).  ``frontier`` and
-    ``rejections`` are populated by the Pareto engine underneath."""
+    """Result shape of ``_greedy_explore``, the greedy search kept as the
+    no-regression oracle (``hls.compile`` returns ``CompileResult``)."""
 
     baseline: DSECandidate
     best: DSECandidate

@@ -1,12 +1,13 @@
 """ILP-scheduled compute/communication overlap (DESIGN.md §3).
 
-The ring all-gather matmul in parallel/collective_matmul.py interleaves one
-ICI hop with one MXU matmul per step.  Here the interleave is *derived* with
-the paper's scheduler: the ICI link and the MXU are single-port memories,
-each ring step is one loop iteration whose body sends chunk k (ICI port) and
-multiplies chunk k-1 (MXU port, RAW-dependent on the previous receive).  The
-scheduler proves II = 1 (send and matmul overlap) — while a naive dependence
-chain (gather fully, then multiply) costs II = 2.
+A ring all-gather matmul interleaves one ICI hop with one MXU matmul per
+step.  Here the interleave is *derived* with the paper's scheduler: the ICI
+link and the MXU are single-port memories, each ring step is one loop
+iteration whose body sends chunk k (ICI port) and multiplies chunk k-1 (MXU
+port, RAW-dependent on the previous receive).  The scheduler proves II = 1
+(send and matmul overlap) — while a naive dependence chain (gather fully,
+then multiply) costs II = 2.  Only the plan is derived; no collective kernel
+in this tree runs it.
 """
 from __future__ import annotations
 

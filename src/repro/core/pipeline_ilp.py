@@ -17,8 +17,8 @@ instead of hard-coding one, and handles non-SPSC stage graphs — e.g. an
 encoder output consumed by every decoder stage's cross-attention — which is
 precisely the pattern Vitis-style FIFO dataflow cannot express (§2).
 
-The executor in repro/parallel/pipeline.py realizes the derived schedule with
-shard_map + lax.ppermute.
+The schedule is derived and measured in ticks; no executor runs it on
+devices.
 """
 from __future__ import annotations
 

@@ -16,10 +16,7 @@ bit-exact agreement), and the block/halo configuration is read off the
 generated kernel — ``hls.compile`` shift-and-peel-fuses the mismatched-bounds
 chain, the knee point of the latency x BRAM Pareto frontier is lowered with
 ``CompileResult.emit_pallas()``, and the kernel's ``block_rows`` / ``halo``
-supply both values (the fusion's row shift IS the halo).  The older fixed
-probe (``ilp_halo_rows``) is kept only as the fallback when the sweep finds
-no shifted fusion.  ``stencil_dse_config`` remains as a deprecated wrapper
-(DESIGN.md §6 MIGRATION).
+supply both values (the fusion's row shift IS the halo).
 
 This module owns the single implementation; ``repro.kernels.ops`` re-exports
 it.  ``interpret`` defaults to compiled: a call on a host with no TPU fails
@@ -28,7 +25,6 @@ unless it asks for Pallas interpret mode explicitly.
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +70,7 @@ def stencil_pipeline(img, wx, wy, *, block_rows=None, halo=None,
                      interpret=False):
     """img: (H, W); wx, wy: (3,).  Returns conv_y(conv_x(img)) of shape
     (H-2, W-2), computed in one fused pass.  ``block_rows``/``halo`` default
-    to the DSE-derived configuration (``stencil_dse_config``)."""
+    to the DSE-derived configuration (``_stencil_codegen_config``)."""
     if block_rows is None or halo is None:
         dse_rows, dse_halo = _stencil_codegen_config()
         block_rows = dse_rows if block_rows is None else block_rows
@@ -88,69 +84,26 @@ def stencil_pipeline(img, wx, wy, *, block_rows=None, halo=None,
 
 
 @functools.lru_cache()
-def ilp_halo_rows(taps: int = 3) -> int:
-    """Fallback fixed probe (demoted: the ``emit_pallas`` sweep in
-    ``_stencil_codegen_config`` is the primary source): derive the
-    line-buffer halo from the paper's memory-dependence
-    ILP by scheduling a two-nest conv chain and converting the
-    producer->consumer slack into rows (slack = -(halo rows) * II_row).
+def _stencil_codegen_config(taps: int = 3, n: int = 8) -> tuple[int, int]:
+    """(block_rows, halo) for ``stencil_pipeline``, read off the generated
+    kernel of the DSE knee point.
 
-    The two-nest chain is produced by the pass pipeline rather than built by
-    hand: the producer is written as raw accumulation + a pointwise scale
-    nest, and ``FuseProducerConsumer`` (equal-bounds mode, with an exact ILP
-    legality proof) collapses them into the single producer nest whose RAW
-    edges on ``mid`` carry the halo."""
-    from repro.core.autotune import compile_program
-    from repro.core.ir import ProgramBuilder
-    from repro.core.transforms import (FuseProducerConsumer, Normalize,
-                                       PassManager)
+    ``hls.compile`` explores transform pipelines over the mismatched-bounds
+    blur chain; the knee of the latency x BRAM curve among the candidates
+    that shift-and-peel fused the intermediate ``bx`` is lowered with
+    ``CompileResult.emit_pallas()`` and the kernel reports its own config:
+    ``PallasKernel.halo["bx"]`` is the fusion's row shift (the number of
+    producer rows the consumer trails by — the line-buffer halo) and
+    ``PallasKernel.block_rows`` the knee's tiling of the fused row loop.
+    Raises ``RuntimeError`` when no frontier point shift-fused ``bx`` or the
+    knee does not lower.
 
-    n = 8
-    b = ProgramBuilder("halo_probe")
-    Hm = n + taps - 1
-    b.array("img", (n + 2 * (taps - 1), n), partition=(0, 1), ports=("w", "r"))
-    b.array("acc", (Hm, n), partition=(0, 1), ports=("w", "r"))
-    b.array("mid", (Hm, n), partition=(0, 1), ports=("w", "r"))
-    b.array("out", (n, n), partition=(0, 1), ports=("w", "r"))
-    # producer, unfused form: accumulate taps, then scale pointwise
-    with b.loop("pi", 0, Hm) as i:
-        with b.loop("pj", 0, n) as j:
-            t = [b.load("img", i + t_, j) for t_ in range(taps)]
-            b.store("acc", b.sum_tree(t), i, j)
-    with b.loop("si", 0, Hm) as i:
-        with b.loop("sj", 0, n) as j:
-            b.store("mid", b.mul(b.load("acc", i, j), b.const(1.0 / taps)), i, j)
-    # consumer conv over the fused producer's output
-    with b.loop("ci", 0, n) as i:
-        with b.loop("cj", 0, n) as j:
-            t = [b.mul(b.load("mid", i + t_, j), b.const(1.0 / taps))
-                 for t_ in range(taps)]
-            b.store("out", b.sum_tree(t), i, j)
-    # equal-bounds fusion only: the probe MEASURES the cross-nest slack, so
-    # the consumer must stay a separate nest (shift fusion would absorb it)
-    p = PassManager([Normalize(), FuseProducerConsumer(enable_shift=False)],
-                    verify=True).run(b.build())
-    assert len(p.body) == 2, "accumulate+scale must fuse into the producer"
-    s = compile_program(p)
-    prod, _ = p.body
-    ii_row = s.iis[prod.uid]
-    # the RAW dependence edges on `mid` carry the slack: lower = delay - slack
-    # = wr_latency + halo_rows * II_row; the worst edge is the deepest tap.
-    worst = max(e.lower for e in s.edges
-                if e.kind == "RAW" and e.array == "mid")
-    return max(1, -(-(worst - 1) // ii_row))  # ceil
-
-
-# (taps, n) -> "dse" or "fallback(<reason>)": which path produced the config
-# returned by _stencil_codegen_config — tests assert the DSE sweep actually
-# ran, so a silently broken sweep cannot hide behind the fallback's values.
-_CONFIG_SOURCE: dict[tuple[int, int], str] = {}
-
-
-def _stencil_dse_sweep(taps: int, n: int) -> tuple[int, int]:
-    """Run the hls.compile Pareto sweep, lower the knee point with
-    ``emit_pallas``, and read (block_rows, halo) off the generated kernel;
-    raises RuntimeError when no frontier point shift-fused bx."""
+    Persistence rides the compile cache: ``hls.compile`` stores the whole
+    frontier content-addressed (``repro.core.cache``), so a serving process
+    pays the sweep once per machine and this function only replays a cache
+    hit.  The ``lru_cache`` on top memoizes the in-process lookups; cache
+    entries carry the scheduler salt, so a compiler change invalidates them
+    and the sweep reruns."""
     from repro.core import hls
     from repro.core.errors import UnlowerableProgram
     from repro.core.programs import blur_chain
@@ -185,53 +138,3 @@ def _stencil_dse_sweep(taps: int, n: int) -> tuple[int, int]:
     except UnlowerableProgram as e:
         raise RuntimeError(f"knee point unlowerable: {e}") from e
     return kern.block_rows, kern.halo["bx"]
-
-
-@functools.lru_cache()
-def _stencil_codegen_config(taps: int = 3, n: int = 8) -> tuple[int, int]:
-    """(block_rows, halo) for ``stencil_pipeline``, read off the generated
-    kernel of the DSE knee point.
-
-    ``hls.compile`` explores transform pipelines over the mismatched-bounds
-    blur chain; the knee of the latency x BRAM curve among the candidates
-    that shift-and-peel fused the intermediate ``bx`` is lowered with
-    ``CompileResult.emit_pallas()`` and the kernel reports its own config:
-    ``PallasKernel.halo["bx"]`` is the fusion's row shift (the number of
-    producer rows the consumer trails by — the line-buffer halo) and
-    ``PallasKernel.block_rows`` the knee's tiling of the fused row loop.
-    Falls back to the fixed ``ilp_halo_rows`` probe if the sweep yields no
-    shifted fusion; ``stencil_config_source`` reports which path produced
-    the values.
-
-    Persistence rides the PR 6 compile cache: ``hls.compile`` stores the
-    whole frontier content-addressed (``repro.core.cache``), so a serving
-    process pays the sweep once per machine and this function only replays
-    a cache hit — no private side entry needed.  The ``lru_cache`` on top
-    memoizes the in-process lookups; cache entries carry the scheduler
-    salt, so a compiler change invalidates them and the sweep reruns."""
-    try:
-        cfg = _stencil_dse_sweep(taps, n)
-        _CONFIG_SOURCE[(taps, n)] = "dse"
-    except RuntimeError as e:  # demoted fixed-probe fallback
-        _CONFIG_SOURCE[(taps, n)] = f"fallback({e})"
-        cfg = 8, ilp_halo_rows(taps)
-    return cfg
-
-
-def stencil_dse_config(taps: int = 3, n: int = 8) -> tuple[int, int]:
-    """Deprecated wrapper (DESIGN.md §6 MIGRATION): the blessed path is
-    ``hls.compile(blur_chain(...)).emit_pallas()`` — the generated kernel
-    carries ``block_rows``/``halo`` itself.  Old signature kept; delegates
-    to the same config the kernel defaults use."""
-    warnings.warn(
-        "stencil_dse_config is deprecated; use hls.compile(...)"
-        ".emit_pallas() and read PallasKernel.block_rows / .halo "
-        "(DESIGN.md §6 MIGRATION)", DeprecationWarning, stacklevel=2)
-    return _stencil_codegen_config(taps, n)
-
-
-def stencil_config_source(taps: int = 3, n: int = 8) -> str:
-    """'dse' when the stencil config values came from the emit_pallas
-    sweep, else 'fallback(<reason>)'."""
-    _stencil_codegen_config(taps, n)
-    return _CONFIG_SOURCE[(taps, n)]

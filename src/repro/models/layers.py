@@ -1,9 +1,8 @@
 """Shared model layers (pure functions over pytrees of jnp arrays).
 
-Everything is written to be (a) `lax.scan`-stackable over layers so the HLO
-stays compact for 512-device dry-run compiles, and (b) shardable by the
-declarative rules in ``repro/parallel/sharding.py`` (attention heads / FFN
-columns on the "model" axis, batch on "data"/"pod", experts on "model").
+Everything is written to be `lax.scan`-stackable over layers so the HLO
+stays compact.  The layers are single-device: they are kept as the reference
+definitions for compiling traced layers through ``frontend.trace``.
 """
 from __future__ import annotations
 
@@ -83,23 +82,17 @@ def _sdpa(cfg, q, k, v, mask, dtype):
     cfg.scores_bf16 keeps the (S x T) score tensor in bf16 with fp32 row
     statistics (flash-attention numerics) — halves the dominant attention
     traffic on memory-bound train/prefill cells (§Perf)."""
-    from repro.parallel.sharding import constrain
     hd = q.shape[-1]
-    q = constrain(q, "dp", None, "model", None)
-    k = constrain(k, "dp", None, "model", None)
-    v = constrain(v, "dp", None, "model", None)
     sd = jnp.bfloat16 if cfg.scores_bf16 else jnp.float32
     scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(sd) * \
         jnp.asarray(hd ** -0.5, sd)
-    scores = constrain(scores, "dp", "model", None, None)
     neg = jnp.asarray(jnp.finfo(sd).min / 2, sd)
     scores = jnp.where(mask, scores, neg)
     m = jax.lax.stop_gradient(scores.max(axis=-1, keepdims=True))
     p = jnp.exp(scores - m)
     l = jnp.sum(p, axis=-1, keepdims=True, dtype=jnp.float32)
     w = (p / l.astype(sd)).astype(dtype)
-    o = jnp.einsum("bhst,bthd->bshd", w, v)
-    return constrain(o, "dp", None, "model", None)
+    return jnp.einsum("bhst,bthd->bshd", w, v)
 
 
 def _sdpa_chunked(cfg, q, k, v, pos_q, pos_k, dtype):
@@ -108,12 +101,11 @@ def _sdpa_chunked(cfg, q, k, v, pos_q, pos_k, dtype):
     softmax — the (S x T) score matrix never materializes at once; the mask
     is an iota comparison per block instead of a (B,1,S,T) bool tensor.
     q: (B,S,H,hd); k,v: (B,T,H,hd); pos_*: (B,S)/(B,T)."""
-    from repro.parallel.sharding import constrain
     B, S, H, hd = q.shape
     T = k.shape[1]
     C = min(cfg.attn_chunk, T)
     assert T % C == 0, (T, C)
-    q = constrain(q, "dp", None, "model", None).astype(jnp.float32)
+    q = q.astype(jnp.float32)
     scale = hd ** -0.5
     kc = k.reshape(B, T // C, C, H, hd).swapaxes(0, 1)
     vc = v.reshape(B, T // C, C, H, hd).swapaxes(0, 1)
@@ -137,8 +129,7 @@ def _sdpa_chunked(cfg, q, k, v, pos_q, pos_k, dtype):
     m0 = jnp.full((B, H, S), -1e30, jnp.float32)
     l0 = jnp.zeros((B, H, S), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0), (kc, vc, pc))
-    o = (acc / (l[..., None] + 1e-30)).swapaxes(1, 2).astype(dtype)
-    return constrain(o, "dp", None, "model", None)
+    return (acc / (l[..., None] + 1e-30)).swapaxes(1, 2).astype(dtype)
 
 
 def attn_forward(cfg: ArchConfig, p, x, positions, causal=True):
@@ -233,17 +224,13 @@ def _mla_qkv(cfg, p, h, positions):
 
 def _mla_attend(cfg, p, x, q_nope, q_rope, c_kv, k_rope, valid):
     """c_kv: (B, T, r); k_rope: (B, T, rope_hd) shared across heads."""
-    from repro.parallel.sharding import constrain
     m = cfg.mla
     B, S = q_nope.shape[:2]
     kv = jnp.einsum("btr,rhe->bthe", c_kv, p["wukv"])
-    kv = constrain(kv, "dp", None, "model", None)
     k_nope, v = jnp.split(kv, [m.nope_head_dim], axis=-1)
-    q_nope = constrain(q_nope, "dp", None, "model", None)
     sc = jnp.einsum("bshq,bthq->bhst", q_nope, k_nope)
     sc = sc + jnp.einsum("bshq,btq->bhst", q_rope, k_rope)
     sc = sc.astype(jnp.float32) * ((m.nope_head_dim + m.rope_head_dim) ** -0.5)
-    sc = constrain(sc, "dp", "model", None, None)
     sc = jnp.where(valid, sc, -1e30)
     w = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
     o = jnp.einsum("bhst,bthv->bshv", w, v)
@@ -298,7 +285,6 @@ def init_mlp(cfg: ArchConfig, key, d_ff=None):
 
 
 def mlp_forward(cfg: ArchConfig, p, x):
-    from repro.parallel.sharding import constrain
     h = rms_norm(x, p["norm"], cfg.norm_eps)
     up = h @ p["w_up"]
     if cfg.act == "swiglu":
@@ -307,7 +293,6 @@ def mlp_forward(cfg: ArchConfig, p, x):
         up = jax.nn.gelu(h @ p["w_gate"]) * up
     else:  # gelu (whisper-style 2-matrix MLP)
         up = jax.nn.gelu(up)
-    up = constrain(up, "dp", None, "model")
     return x + up @ p["w_down"]
 
 
@@ -336,10 +321,7 @@ def moe_forward(cfg: ArchConfig, p, x):
     — at DeepSeek/Kimi scale that dwarfs the experts themselves (observed
     175x overcount in the dry-run roofline).  Instead we scatter token ids
     into (E, C) slot tables and gather activations, so dispatch costs bytes,
-    not FLOPs.  Groups = batch rows (data-sharded); experts shard over
-    "model" (EP) and the gathers become XLA all-to-alls."""
-    from repro.parallel.sharding import constrain
-
+    not FLOPs.  Groups = batch rows."""
     B, S, D = x.shape
     mc = cfg.moe
     E, K = mc.n_experts, mc.top_k
@@ -375,11 +357,9 @@ def moe_forward(cfg: ArchConfig, p, x):
     # dispatch: gather tokens into (G, E, C, D) expert buffers
     xin = jnp.take_along_axis(h, src[..., None], axis=1) * vld[..., None]
     xin = xin.reshape(G, E, C, D)
-    xin = constrain(xin, "dpx", "ep", None, None)
     act = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xin, p["w_gate"]))
     mid = act * jnp.einsum("gecd,edf->gecf", xin, p["w_up"])
     xout = jnp.einsum("gecf,efd->gecd", mid, p["w_down"])
-    xout = constrain(xout, "dpx", "ep", None, None)
     # combine: gather each (t, k)'s slot back and weight by the gate
     flat = xout.reshape(G, E * C, D)
     vals = jnp.take_along_axis(
@@ -457,8 +437,6 @@ def mamba_forward(cfg: ArchConfig, p, x, chunk=256):
     kc = cfg.mamba_d_conv
     h = rms_norm(x, p["norm"], cfg.norm_eps)
     xz = jnp.einsum("bsd,de->bse", h, p["w_in"])
-    from repro.parallel.sharding import constrain
-    xz = constrain(xz, "dp", None, "model")
     chunk = min(chunk, S)
     assert S % chunk == 0, "seq_len must be divisible by the mamba chunk"
     xz_c = xz.reshape(B, S // chunk, chunk, 2 * di).swapaxes(0, 1)
@@ -568,10 +546,8 @@ def rwkv_time_mix(cfg: ArchConfig, p, x, shift_last, s0, chunk=128):
     def heads(t):
         return t.reshape(B, S, H, hd).swapaxes(1, 2)      # (B,H,S,hd)
 
-    from repro.parallel.sharding import constrain
-    rh, kh, vh, wh = map(
-        lambda t: constrain(heads(t).astype(jnp.float32),
-                            "dp", "model", None, None), (r, k, v, w))
+    rh, kh, vh, wh = map(lambda t: heads(t).astype(jnp.float32),
+                         (r, k, v, w))
     u = p["u_bonus"].reshape(H, hd)
     chunk = min(chunk, S)
     assert S % chunk == 0

@@ -10,11 +10,9 @@ Covers, per the API-redesign acceptance criteria:
     n=8 (the Fig. 9 trade-off curve is deterministic);
   * no-regression vs the old greedy explore(): the new frontier contains a
     point dominating-or-equal to the greedy winner;
-  * the deprecated shims (repro.core.explore / compile_program) emit
-    exactly one DeprecationWarning per access and still work.
+  * the retired entry points (repro.core.explore / compile_program) are
+    gone from the package surface.
 """
-import warnings
-
 import numpy as np
 import pytest
 
@@ -390,85 +388,40 @@ def test_frontier_dominates_greedy_winner_full(name):
 
 
 # ---------------------------------------------------------------------------
-# Deprecated shims
+# Retired entry points
 # ---------------------------------------------------------------------------
 
 
-def _access_explore():
+@pytest.mark.parametrize("name", ["compile_program", "explore"])
+def test_retired_entry_points_are_gone(name):
+    """``repro.core`` no longer serves the retired DSE entry points; the
+    schedule-only compile stays in ``repro.core.autotune``."""
     import repro.core
-    return repro.core.explore
-
-
-def _access_compile_program():
-    import repro.core
-    return repro.core.compile_program
-
-
-def _access_stencil_dse_config():
-    from repro.kernels.stencil_pipeline import stencil_dse_config
-    return stencil_dse_config(3, 8)
-
-
-@pytest.mark.parametrize("name,access,blessed", [
-    ("explore", _access_explore, "hls.compile"),
-    ("compile_program", _access_compile_program, "hls.compile"),
-    ("stencil_dse_config", _access_stencil_dse_config, "emit_pallas"),
-], ids=["explore", "compile_program", "stencil_dse_config"])
-def test_deprecated_shim_warns_exactly_once_per_access(name, access, blessed):
-    """Every deprecated shim emits exactly one DeprecationWarning per
-    access, names itself, and points at the blessed replacement."""
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        access()
-    dep = [x for x in w if issubclass(x.category, DeprecationWarning)]
-    assert len(dep) == 1, (name, [str(x.message) for x in w])
-    assert name in str(dep[0].message)
-    assert blessed in str(dep[0].message)
-    assert "MIGRATION" in str(dep[0].message)
-
-
-def test_blessed_path_does_not_warn():
-    import repro.core
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        hls.compile(two_mm(4), pipeline=())
-    assert not [x for x in w if issubclass(x.category, DeprecationWarning)]
-    with pytest.raises(AttributeError):
-        repro.core.no_such_attribute
-
-
-def test_deprecated_explore_still_works():
-    import repro.core
-    p = blur_chain(8, storage="bram")
-    r = repro.core.explore(p, max_candidates=6, unroll_factors=(),
-                           tile_sizes=())
-    assert r.best.latency <= r.baseline.latency
-    assert r.best.within_budget
-    assert r.speedup >= 1.0
-    assert r.frontier  # the shim surfaces the Pareto frontier too
-    s = repro.core.compile_program(p)
-    assert s.completion_time() == r.baseline.latency
+    from repro.core.autotune import compile_program
+    with pytest.raises(ImportError):
+        exec(f"from repro.core import {name}", {})
+    assert not hasattr(repro.core, name)
+    assert not hasattr(hls, name)
+    assert compile_program(two_mm(4)).feasible
 
 
 # ---------------------------------------------------------------------------
-# Graceful empty-budget behavior (DSEResult satellite)
+# Graceful empty-budget behavior
 # ---------------------------------------------------------------------------
 
 
 def test_explore_rejecting_budget_returns_baseline():
-    """A budget no candidate can meet must return the baseline gracefully
-    (no ZeroDivisionError, no arbitrary over-budget 'winner') and record
-    every rejection reason."""
-    import repro.core
+    """A DSP constraint no candidate can meet must return the baseline
+    gracefully (no ZeroDivisionError, no arbitrary over-budget 'winner')
+    and record every rejection reason."""
     p = two_mm(4)
-    r = repro.core.explore(p, budget={"dsp": 0.0}, max_candidates=4,
-                           unroll_factors=(), tile_sizes=())
+    r = hls.compile(p, constraints=("dsp <= 0",), search=hls.SearchConfig(
+        max_candidates=4, unroll_factors=(), tile_sizes=()))
     assert r.best is r.baseline
     assert not r.best.within_budget
+    assert not r.frontier
     assert r.speedup == 1.0          # guarded division
-    assert r.table()                 # no crash on all-over-budget rows
-    assert r.rejections and all("dsp" in reason
-                                for _, reason in r.rejections)
+    assert r.rejected and all("dsp" in reason for _, reason in r.rejected)
     assert "over budget" in r.explain()
 
 

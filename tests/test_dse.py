@@ -1,23 +1,28 @@
-"""Resource-aware DSE driver (autotune.explore, DESIGN.md §6).
+"""Resource-aware DSE (``hls.compile`` without a fixed pipeline, DESIGN.md
+§6).
 
-The acceptance demo: for real benchmarks, ``explore`` must find a
-transformed program whose scheduled latency beats the untransformed
-``compile_program`` schedule at equal-or-lower BRAM/DSP, and the winner
-must pass the brute-force schedule validator + timed-execution oracle
-(``validate=True`` asserts both inside explore).
+The acceptance demo: for real benchmarks, the DSE must find a transformed
+program whose scheduled latency beats the untransformed ``compile_program``
+schedule at equal-or-lower BRAM/DSP, and the winner must pass the
+brute-force schedule validator + timed-execution oracle
+(``SearchConfig(validate=True)`` asserts both inside the compile).
 """
 import pytest
 
-from repro.core.api import explore
+from repro.core import hls
 from repro.core.autotune import compile_program
 from repro.core.programs import harris, two_mm, unsharp
+
+#: the iso-resource budget: the baseline design's own BRAM and DSP
+ISO_RESOURCE = ("bram <= 1.0x baseline", "dsp <= 1.0x baseline")
 
 
 @pytest.mark.parametrize("mk,n", [(two_mm, 6), (harris, 6)])
 def test_explore_beats_baseline_iso_resources(mk, n):
     p = mk(n, storage="bram")
-    r = explore(p, verify=True, validate=True, max_candidates=8,
-                unroll_factors=(2,), tile_sizes=())
+    r = hls.compile(p, constraints=ISO_RESOURCE, search=hls.SearchConfig(
+        verify=True, validate=True, max_candidates=8, unroll_factors=(2,),
+        tile_sizes=()))
     assert r.best.latency < r.baseline.latency, (r.best.desc, r.best.latency)
     assert r.best.res["bram_bytes"] <= r.baseline.res["bram_bytes"] + 1e-9
     assert r.best.res["dsp"] <= r.baseline.res["dsp"] + 1e-9
@@ -27,33 +32,36 @@ def test_explore_beats_baseline_iso_resources(mk, n):
 
 def test_explore_default_budget_is_iso_resource():
     p = two_mm(4)
-    r = explore(p, verify=True, max_candidates=4, unroll_factors=(),
-                tile_sizes=())
-    assert r.budget == {"bram_bytes": r.baseline.res["bram_bytes"],
-                        "dsp": r.baseline.res["dsp"]}
+    r = hls.compile(p, constraints=ISO_RESOURCE, search=hls.SearchConfig(
+        verify=True, max_candidates=4, unroll_factors=(), tile_sizes=()))
+    assert r.caps == {"bram_bytes": r.baseline.res["bram_bytes"],
+                      "dsp": r.baseline.res["dsp"]}
     for c in r.candidates:
         assert c.within_budget == all(
-            c.res[k] <= v + 1e-9 for k, v in r.budget.items())
+            c.res[k] <= v + 1e-9 for k, v in r.caps.items())
 
 
 def test_explore_budget_gates_unroll():
     """Unrolling doubles datapath DSPs: it must be flagged over-budget under
     the iso-resource budget, but become eligible when the budget allows."""
     p = unsharp(8, storage="bram")
-    iso = explore(p, max_candidates=6, unroll_factors=(2,), tile_sizes=())
+    search = hls.SearchConfig(max_candidates=6, unroll_factors=(2,), tile_sizes=())
+    iso = hls.compile(p, constraints=ISO_RESOURCE, search=search)
     unrolled = [c for c in iso.candidates if "unroll" in c.desc]
     assert unrolled and all(not c.within_budget for c in unrolled)
     assert iso.best.within_budget
 
-    roomy = explore(p, budget={"dsp": 1e9, "bram_bytes": 1e9},
-                    max_candidates=6, unroll_factors=(2,), tile_sizes=())
+    roomy = hls.compile(p, constraints=("dsp <= 1000000000",
+                                        "bram <= 1000000000"),
+                        search=search)
     unrolled = [c for c in roomy.candidates if "unroll" in c.desc]
     assert unrolled and all(c.within_budget for c in unrolled)
 
 
 def test_explore_baseline_matches_compile_program():
     p = two_mm(4)
-    r = explore(p, max_candidates=2, unroll_factors=(), tile_sizes=())
+    r = hls.compile(p, constraints=ISO_RESOURCE, search=hls.SearchConfig(
+        max_candidates=2, unroll_factors=(), tile_sizes=()))
     assert r.baseline.latency == compile_program(p).completion_time()
     assert r.best.latency <= r.baseline.latency
 
@@ -63,8 +71,9 @@ def test_explore_enumerates_shifted_fusion():
     with) a shift-and-peel fused candidate under the iso-resource budget."""
     from repro.core.programs import blur_chain
     p = blur_chain(8, storage="bram")
-    r = explore(p, verify=True, validate=True, max_candidates=8,
-                unroll_factors=(), tile_sizes=())
+    r = hls.compile(p, constraints=ISO_RESOURCE, search=hls.SearchConfig(
+        verify=True, validate=True, max_candidates=8, unroll_factors=(),
+        tile_sizes=()))
     fused = [c for c in r.candidates if getattr(c.program, "_fusion_log", [])]
     assert fused, "no shifted-fusion candidate enumerated"
     best_fused = min(fused, key=lambda c: c.latency)
@@ -108,7 +117,6 @@ def test_frontier_point_differs_by_tile_size():
     """At least one Pareto frontier point must differ from another by its
     tile size (the ISSUE acceptance for the VMEM/BRAM footprint term), and
     the tiled point must be strictly cheaper in BRAM."""
-    from repro.core import hls
     from repro.core.programs import blur_chain
     from repro.core.transforms import LoopTile
 
@@ -133,10 +141,8 @@ def test_frontier_point_differs_by_tile_size():
     # via the knee point's generated kernel (emit_pallas), which rounds the
     # tile up to the chip's row tile
     from repro.core.codegen import SUBLANE_ROWS
-    from repro.kernels.stencil_pipeline import (_stencil_codegen_config,
-                                                stencil_config_source)
+    from repro.kernels.stencil_pipeline import _stencil_codegen_config
     block_rows, halo = _stencil_codegen_config()
-    assert stencil_config_source() == "dse"
     assert halo == 2
     knee = r.knee("latency", "bram", among=tiled)
     assert block_rows in {-(-t // SUBLANE_ROWS) * SUBLANE_ROWS

@@ -53,26 +53,18 @@ def test_stencil_pipeline_single_implementation():
     same function object."""
     from repro.kernels import stencil_pipeline as spmod
     assert ops.stencil_pipeline is spmod.stencil_pipeline
-    assert ops.ilp_halo_rows is spmod.ilp_halo_rows
-    assert ops.stencil_dse_config is spmod.stencil_dse_config
 
 
 def test_stencil_config_from_dse_sweep():
     """The kernel's block/halo config is read off the generated kernel of
     the DSE knee point (emit_pallas): the winning fusion's row shift is the
-    halo.  It must agree with the (demoted, fallback-only) fixed probe for
-    the 3-tap chain — and must actually have COME from the sweep, not from
-    the fallback quietly returning the same values.  The old entry point
-    survives as a deprecated wrapper with the same values."""
+    halo, two rows for the 3-tap chain, and the block rows are the knee's
+    tile rounded up to the chip's row tile."""
     from repro.core.codegen import SUBLANE_ROWS
-    from repro.kernels.stencil_pipeline import (_stencil_codegen_config,
-                                                stencil_config_source)
+    from repro.kernels.stencil_pipeline import _stencil_codegen_config
     block_rows, halo = _stencil_codegen_config()
-    assert stencil_config_source() == "dse"
-    assert halo == 2 == ops.ilp_halo_rows(3)
+    assert halo == 2
     assert block_rows >= SUBLANE_ROWS and block_rows % SUBLANE_ROWS == 0
-    with pytest.warns(DeprecationWarning, match="emit_pallas"):
-        assert ops.stencil_dse_config() == (block_rows, halo)
 
 
 def test_stencil_pipeline_dse_default_config():
